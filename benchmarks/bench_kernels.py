@@ -89,8 +89,8 @@ def _attention_rows(rows) -> None:
 
     s_len = 128
     qd = jax.random.normal(ks[0], (b, 1, h, d), jnp.float32)
-    kd = jax.random.normal(ks[1], (b, s_len, kv, d), jnp.float32)
-    vd = jax.random.normal(ks[2], (b, s_len, kv, d), jnp.float32)
+    kd = jax.random.normal(ks[1], (b, kv, s_len, d), jnp.float32)
+    vd = jax.random.normal(ks[2], (b, kv, s_len, d), jnp.float32)
     pos = jnp.array([17, 103])
     for name, pol in POLICIES:
         us = time_call(jax.jit(lambda *x_, p=pol: dispatch.decode_attention(

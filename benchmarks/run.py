@@ -104,4 +104,6 @@ if __name__ == "__main__":
                          "BENCH_train.json at the repo root (rows + "
                          "engine stats)")
     args = ap.parse_args()
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main(smoke=args.smoke, emit_json=args.json)

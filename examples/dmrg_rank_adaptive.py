@@ -13,11 +13,13 @@ from repro.config.base import OptimizerConfig, RunConfig, SHAPES, TrainConfig
 from repro.core import tt
 from repro.core.dmrg import RankSchedule
 from repro.data import LMStream
+from repro.launch import compile_cache
 from repro.peft import api as peft_api
 from repro.train.trainer import Trainer
 
 
 def main():
+    compile_cache.enable()
     cfg = registry.get_smoke_config("roberta-base")
     run = RunConfig(model=cfg, shape=SHAPES["train_4k"],
                     adapter_kind="metatt", adapter_rank=10,

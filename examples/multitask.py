@@ -15,6 +15,7 @@ import numpy as np
 from repro import configs as registry
 from repro.config.base import OptimizerConfig, RunConfig, SHAPES, TrainConfig
 from repro.data import ClassificationTasks
+from repro.launch import compile_cache
 from repro.models import model as M, transformer as T
 from repro.optim import adamw
 from repro.peft import api as peft_api
@@ -33,6 +34,7 @@ def core_grad_norms(tr, batch):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--grad-heatmap", action="store_true")
     ap.add_argument("--pretrain-steps", type=int, default=150)

@@ -11,6 +11,7 @@ import numpy as np
 from repro import configs as registry
 from repro.config.base import OptimizerConfig, RunConfig, SHAPES, TrainConfig
 from repro.data import LMStream
+from repro.launch import compile_cache
 from repro.peft import api as peft_api
 from repro.train.trainer import Trainer
 
@@ -31,6 +32,7 @@ def train_one(adapter_kind: str, steps: int):
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=80)
     args = ap.parse_args()

@@ -43,6 +43,7 @@ from repro import configs as registry
 from repro.config.base import (RegistryConfig, RunConfig, SHAPES,
                                ServeConfig)
 from repro.core import tt as ttlib
+from repro.launch import compile_cache
 from repro.models import model as M
 from repro.serving import (AdapterRuntime, ChaosInjector, Engine, FINISHED,
                            Request, SpecConfig, audit)
@@ -95,6 +96,7 @@ def serve(cfg, runtime, reqs, *, max_batch, cache_len, out_cap, tp=0,
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tokens", type=int, default=16)
     ap.add_argument("--requests", type=int, default=8)

@@ -12,11 +12,13 @@ import numpy as np
 from repro import configs as registry
 from repro.config.base import OptimizerConfig, RunConfig, SHAPES, TrainConfig
 from repro.data import LMStream
+from repro.launch import compile_cache
 from repro.peft import api as peft_api
 from repro.train.trainer import Trainer
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-1.6b",
                     choices=list(registry.ARCH_IDS) + ["roberta-base"])

@@ -32,7 +32,7 @@ stack follows.
 Sharded serving (DESIGN.md §9): these entry points are shard_map-safe —
 under the engine's tensor-parallel mesh each shard calls them with its
 LOCAL head group and LOCAL KV-pool shard (q (B, C, H/tp, d) against
-(N, page, KV/tp, d) pools), which is just a smaller instance of the
+(N, KV/tp, page, d) pools), which is just a smaller instance of the
 single-device shapes documented below; no kernel knows about the mesh.
 The ambient-GSPMD guard lives one level up (models/attention.py::
 _flash_ok): kernels stand down under an ambient >1-chip mesh, but run
@@ -301,8 +301,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
 def decode_attention(q, k, v, pos, *,
                      policy: Optional[KernelPolicy] = None):
-    """Cached single-token decode. q: (B, 1, H, d); k, v: (B, S, KV, d);
-    pos: scalar or (B,) per-slot positions -> (B, 1, H, d)."""
+    """Cached single-token decode. q: (B, 1, H, d); k, v: (B, KV, S, d)
+    head-major caches; pos: scalar or (B,) per-slot positions ->
+    (B, 1, H, d)."""
     if policy is not None and policy.flash_attn:
         return ops.decode_attention(q, k, v, pos, backend="pallas",
                                     interpret=policy.interpret,
@@ -314,9 +315,9 @@ def paged_decode_attention(q, k_cache, v_cache, tables, pos, *,
                            k_scale=None, v_scale=None,
                            policy: Optional[KernelPolicy] = None):
     """Paged-cache attention (decode and in-loop chunked prefill).
-    q: (B, C, H, d); k_cache, v_cache: (N, page, KV, d); tables: (B, P)
+    q: (B, C, H, d); k_cache, v_cache: (N, KV, page, d); tables: (B, P)
     int32 block table; pos: (B,) base positions -> (B, C, H, d).
-    k_scale/v_scale: (N, page, KV) per-cell scale pools when the cache is
+    k_scale/v_scale: (N, KV, 1, page) per-cell scale pools when the cache is
     int8 (the kernel dequantizes pages in-register)."""
     if policy is not None and policy.flash_attn:
         return ops.paged_decode_attention(q, k_cache, v_cache, tables, pos,

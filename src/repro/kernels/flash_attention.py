@@ -34,8 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG = -1e30
 
 
@@ -87,7 +85,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float, causal: bool,
         if with_stats:
             # per-row log-sum-exp: the one statistic the blockwise backward
             # needs to rebuild probability tiles as p = exp(s - lse)
-            lse_ref[0] = m_ref[...] + jnp.log(l)
+            lse_ref[0] = (m_ref[...] + jnp.log(l))[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bkv",
@@ -124,7 +122,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -138,7 +136,9 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     """Stats-emitting forward for training: same kernel as
     ``flash_attention`` plus a second output carrying the per-row
     log-sum-exp — the residual the blockwise backward rebuilds probability
-    tiles from. Returns ``(out (BH, T, d), lse (BH, T) f32)``."""
+    tiles from. Returns ``(out (BH, T, d), lse (BH, 1, T) f32)`` — the
+    row statistic lies along lanes, so its (1, 1, bq) blocks meet the
+    TPU's block rule (last two block dims whole or (8, 128)-aligned)."""
     bh, t, d = q.shape
     s_len = k.shape[1]
     assert t % bq == 0 and s_len % bkv == 0, (t, s_len, bq, bkv)
@@ -156,18 +156,18 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, t), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -209,11 +209,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
         if causal:
             qi = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
             valid &= qi >= ki
-        p = jnp.where(valid, jnp.exp(s - lse_ref[0][:, None]), 0.0)
+        p = jnp.where(valid, jnp.exp(s - lse_ref[0, 0][:, None]), 0.0)
         dp = jax.lax.dot_general(
             g, v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # (bq, bkv)
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0, 0][:, None]) * scale
         acc_ref[...] += jax.lax.dot(
             ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
 
@@ -247,14 +247,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dk_ref,
         if causal:
             qi = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
             valid &= qi >= ki
-        p = jnp.where(valid, jnp.exp(s - lse_ref[0][:, None]), 0.0)
+        p = jnp.where(valid, jnp.exp(s - lse_ref[0, 0][:, None]), 0.0)
         dv_acc[...] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # (bkv, d)
         dp = jax.lax.dot_general(
             g, v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # (bq, bkv)
-        ds = p * (dp - delta_ref[0][:, None]) * scale
+        ds = p * (dp - delta_ref[0, 0][:, None]) * scale
         dk_acc[...] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # (bkv, d)
@@ -272,8 +272,10 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         causal: bool = True, bq: int = 256, bkv: int = 256,
                         interpret: bool = True, kv_len: int = 0):
     """Blockwise dq/dk/dv. q, k, v as in ``flash_attention``; o/lse are the
-    stashed forward output + per-row log-sum-exp; g is the output
-    cotangent (BH, T, d). Returns (dq, dk, dv) in the input dtypes.
+    stashed forward output + per-row log-sum-exp (BH, T); g is the output
+    cotangent (BH, T, d). Returns (dq, dk, dv) in the input dtypes. Both
+    row statistics (lse and D) reach the kernels as (BH, 1, T) lane rows
+    in (1, 1, bq) blocks, the layout the TPU's block rule admits.
 
     Zero-padded query rows (callers pad T up to a bq multiple) carry zero
     cotangents, so they contribute nothing to dk/dv; keys at ``ki >=
@@ -285,13 +287,14 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     assert t % bq == 0 and s_len % bkv == 0, (t, s_len, bq, bkv)
     kv_len = kv_len or s_len
     # per-row correction D = rowsum(dO ⊙ O): O(T·d), stays out of kernels
-    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    lse = lse.astype(jnp.float32)
+    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1)[:, None]
+    lse = lse.astype(jnp.float32)[:, None]
     common = dict(scale=d ** -0.5, causal=causal, bq=bq, bkv=bkv,
                   kv_len=kv_len)
     qspec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, bkv, d), lambda b, i, j: (b, j, 0))
-    rowspec = pl.BlockSpec((1, bq), lambda b, i, j: (b, i))
+    rowspec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, kv_steps=s_len // bkv, **common),
         grid=(bh, t // bq, s_len // bkv),
@@ -299,14 +302,14 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, g, lse, delta)
     # dk/dv: kv tiles on the parallel dim, q tiles innermost (sequential)
     qspec2 = pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0))
     kspec2 = pl.BlockSpec((1, bkv, d), lambda b, j, i: (b, j, 0))
-    rowspec2 = pl.BlockSpec((1, bq), lambda b, j, i: (b, i))
+    rowspec2 = pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, i))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, q_steps=t // bq, **common),
         grid=(bh, s_len // bkv, t // bq),
@@ -316,7 +319,7 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    jax.ShapeDtypeStruct((bh, s_len, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bkv, d), jnp.float32),
                         pltpu.VMEM((bkv, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, g, lse, delta)
@@ -332,7 +335,7 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                    acc_ref, *, scale: float, bkv: int, kv_steps: int):
-    j = pl.program_id(2)
+    b, j = pl.program_id(0), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -340,76 +343,86 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    pos = pos_ref[0]                                   # this slot's position
+    pos = pos_ref[b]                                   # this slot's position
     run = j * bkv <= pos                               # skip future kv tiles
 
     @pl.when(run)
     def _block():
-        q = q_ref[0]                                   # (1, d)
-        k = k_ref[0, :, 0]                             # (bkv, d)
+        q = q_ref[0, 0]                                # (1, d)
+        k = k_ref[0, 0]                                # (bkv, d)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (1, bkv)
         ki = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (1, bkv), 1)
         valid = ki <= pos                              # cache cells written
         s = jnp.where(valid, s, NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
+        m_prev = m_ref[...]                            # (1, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
-            p.astype(v_ref.dtype), v_ref[0, :, 0],
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
+            p.astype(v_ref.dtype), v_ref[0, 0],
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(j == kv_steps - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bkv", "interpret"))
 def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      pos: jnp.ndarray, *, bkv: int = 256,
                      interpret: bool = True) -> jnp.ndarray:
-    """q: (B, H, d); k, v: (B, S, KV, d) NATIVE cache layout; pos: (B,)
+    """q: (B, H, d); k, v: (B, KV, S, d) head-major caches; pos: (B,)
     int32 -> (B, H, d).
 
     Row b attends key/value cells [0, pos[b]] of its cache (pos is the cell
-    the current token was just written to); KV tiles strictly beyond a
-    slot's position are skipped entirely. The cache is read in its stored
-    (B, S, KV, d) layout — the GQA broadcast happens in the index map
-    (query head h reads kv head h // G), so the decode loop never
-    materializes a transposed or head-repeated copy of the cache.
+    the current token was just written to). ``pos`` is a scalar-prefetch
+    operand, so the index map can clamp the KV tile index at the slot's
+    position: tiles strictly beyond it repeat the last live tile (no DMA)
+    and skip their compute. The GQA broadcast happens in the index map
+    (query head h reads kv head h // G), so no head-repeated cache copy
+    exists; head-major caches keep every block's last two dims whole —
+    (1, d) queries against (bkv, d) cache tiles.
     """
     b, h, d = q.shape
-    s_len, kv = k.shape[1], k.shape[2]
+    kv, s_len = k.shape[1], k.shape[2]
     g = h // kv
     assert s_len % bkv == 0, (s_len, bkv)
     grid = (b, h, s_len // bkv)
     kernel = functools.partial(_decode_kernel, scale=d ** -0.5, bkv=bkv,
                                kv_steps=grid[2])
-    return pl.pallas_call(
-        kernel,
+
+    def q_map(bi, hi, j, pos_):
+        return (bi, hi, 0, 0)
+
+    def kv_map(bi, hi, j, pos_):
+        return (bi, hi // g, jnp.clip(pos_[bi] // bkv, 0, j), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda bi, hi, j: (bi,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, d), lambda bi, hi, j: (bi, hi, 0)),
-            pl.BlockSpec((1, bkv, 1, d),
-                         lambda bi, hi, j: (bi, j, hi // g, 0)),
-            pl.BlockSpec((1, bkv, 1, d),
-                         lambda bi, hi, j: (bi, j, hi // g, 0)),
+            pl.BlockSpec((1, 1, 1, d), q_map),
+            pl.BlockSpec((1, 1, bkv, d), kv_map),
+            pl.BlockSpec((1, 1, bkv, d), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda bi, hi, j: (bi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, 1, d), q_map),
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(pos.astype(jnp.int32), q, k, v)
+    )(pos.astype(jnp.int32), q[:, :, None], k, v)
+    return out[:, :, 0]

@@ -290,7 +290,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
     out, lse = _flash_fwd(qh, kh, vh, causal=causal, bq=bq, bkv=bkv,
                           interpret=_interp(interpret), kv_len=s0)
     out = out[:, :t0].reshape(b, h, t0, d).transpose(0, 2, 1, 3)
-    return out, lse[:, :t0].reshape(b, h, t0)
+    return out, lse[:, 0, :t0].reshape(b, h, t0)
 
 
 def _group_sum_kv(dx, b: int, kv: int, grp: int, s: int, d: int, dtype):
@@ -359,30 +359,27 @@ def decode_attention(q, k, v, pos, *, backend: str = "auto",
                      interpret: bool | None = None, bkv: int = 0):
     """Cached single-token decode attention with per-row positions.
 
-    q: (B, 1, H, d); k, v: (B, S, KV, d) full-width caches; pos: (B,) — row
-    b attends cache cells [0, pos[b]]. Returns (B, 1, H, d).
+    q: (B, 1, H, d); k, v: (B, KV, S, d) head-major full-width caches
+    (models/attention.py::init_cache); pos: (B,) — row b attends cache
+    cells [0, pos[b]]. Returns (B, 1, H, d).
     """
     b, t, h, d = q.shape
     assert t == 1, "decode attention expects a single query token"
-    s, kv = k.shape[1], k.shape[2]
+    kv, s = k.shape[1], k.shape[2]
     g = h // kv
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     if _use_ref(backend):
         qh = q[:, 0].reshape(b * h, d)
-        kh = (jnp.repeat(k.transpose(0, 2, 1, 3), g, axis=1)
-              .reshape(b * h, s, d))
-        vh = (jnp.repeat(v.transpose(0, 2, 1, 3), g, axis=1)
-              .reshape(b * h, s, d))
+        kh = jnp.repeat(k, g, axis=1).reshape(b * h, s, d)
+        vh = jnp.repeat(v, g, axis=1).reshape(b * h, s, d)
         out = _ref.decode_attention_ref(qh, kh, vh,
                                         jnp.repeat(pos, h))
         return out.reshape(b, 1, h, d)
     bkv = _pick_tile(s, bkv, (256, 128))
-    # the kernel reads the cache in its native (B, S, KV, d) layout (GQA
-    # broadcast happens in its index map), so the decode hot loop never
-    # materializes a transposed / head-repeated cache copy; padded tail
+    # the GQA broadcast happens in the kernel's index map; padded tail
     # cells sit beyond every row's position -> masked by pos
-    kp, _ = _pad_to(k, 1, bkv)
-    vp, _ = _pad_to(v, 1, bkv)
+    kp, _ = _pad_to(k, 2, bkv)
+    vp, _ = _pad_to(v, 2, bkv)
     out = _decode_attn(q[:, 0], kp, vp, pos, bkv=bkv,
                        interpret=_interp(interpret))
     return out[:, None]
@@ -396,26 +393,30 @@ def paged_decode_attention(q, k_cache, v_cache, tables, pos, *,
     + in-loop chunked prefill).
 
     q: (B, C, H, d) — C query tokens per slot, query c of slot b at
-    absolute position pos[b] + c; k_cache, v_cache: (N, page, KV, d) flat
-    block pools; tables: (B, P) int32 logical-page -> physical-block map
-    (sentinel >= N marks unallocated pages); pos: (B,). Returns
-    (B, C, H, d): query c attends cache cells [0, pos[b] + c]. The Pallas
-    kernel gathers blocks in its index map (scalar-prefetched table) so
-    the gathered cache never materializes; the reference path gathers
-    explicitly — same valid set, same logical order.
+    absolute position pos[b] + c; k_cache, v_cache: (N, KV, page, d) flat
+    head-major block pools; tables: (B, P) int32 logical-page ->
+    physical-block map (sentinel >= N marks unallocated pages); pos: (B,).
+    Returns (B, C, H, d): query c attends cache cells [0, pos[b] + c]. The
+    Pallas kernel gathers blocks in its index map (scalar-prefetched
+    table) so the gathered cache never materializes; the reference path
+    gathers explicitly — same valid set, same logical order.
 
-    k_scale/v_scale: optional (N, page, KV) per-cell scale pools for the
+    k_scale/v_scale: optional (N, KV, 1, page) per-cell scale pools for the
     int8 KV mode — the kernel dequantizes pages in-register; the
     reference path dequantizes the pool up front (same math).
     """
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (q.shape[0],))
     if _use_ref(backend):
         if k_scale is not None:
-            k_cache = k_cache.astype(jnp.float32) * k_scale[..., None]
-            v_cache = v_cache.astype(jnp.float32) * v_scale[..., None]
+            k_cache = (k_cache.astype(jnp.float32)
+                       * jnp.swapaxes(k_scale, -1, -2))
+            v_cache = (v_cache.astype(jnp.float32)
+                       * jnp.swapaxes(v_scale, -1, -2))
             return _ref.paged_decode_attention_ref(
                 q, k_cache, v_cache, tables, pos).astype(q.dtype)
         return _ref.paged_decode_attention_ref(q, k_cache, v_cache,
                                                tables, pos)
-    return _paged_attn(q, k_cache, v_cache, tables, pos, k_scale, v_scale,
-                       interpret=_interp(interpret))
+    # the kernel takes queries head-major, (B, H, C, d), like the pools
+    out = _paged_attn(q.transpose(0, 2, 1, 3), k_cache, v_cache, tables,
+                      pos, k_scale, v_scale, interpret=_interp(interpret))
+    return out.transpose(0, 2, 1, 3)

@@ -110,20 +110,20 @@ def paged_decode_attention_ref(q: jnp.ndarray, k_cache: jnp.ndarray,
     allclose target).
 
     q: (B, C, H, d) — C co-batched query tokens per slot, slot b's query c
-    at absolute position pos[b] + c; k_cache, v_cache: (N, page, KV, d)
+    at absolute position pos[b] + c; k_cache, v_cache: (N, KV, page, d)
     flat block pools; tables: (B, P) int32 logical-page -> physical-block
     map (entries may be an out-of-range sentinel: the gather clamps and
     the position mask hides whatever it reads); pos: (B,) base positions.
     Returns (B, C, H, d): query c attends cache cells [0, pos[b] + c].
     """
     b, c, h, d = q.shape
-    n, page, kv, _ = k_cache.shape
+    n, kv, page, _ = k_cache.shape
     g = h // kv
     tbl = jnp.clip(tables, 0, n - 1)
-    # (B, P, page, KV, d) -> (B, S, KV, d) with S = P * page cells in
+    # (B, P, KV, page, d) -> (B, S, KV, d) with S = P * page cells in
     # logical-position order — same valid set, same order as a dense cache
-    kg = k_cache[tbl].reshape(b, -1, kv, d)
-    vg = v_cache[tbl].reshape(b, -1, kv, d)
+    kg = k_cache[tbl].transpose(0, 1, 3, 2, 4).reshape(b, -1, kv, d)
+    vg = v_cache[tbl].transpose(0, 1, 3, 2, 4).reshape(b, -1, kv, d)
     if g > 1:
         kg = jnp.repeat(kg, g, axis=2)
         vg = jnp.repeat(vg, g, axis=2)

@@ -36,8 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _base_dot(x, w_ref, s_ref, per_channel):
     """One K-step of the base matmul. fp (s_ref None): dot in the operand
@@ -117,10 +115,12 @@ def _batched_a_kernel(x_ref, w_ref, *rest, alpha: float, k_steps: int,
 
     x = x_ref[...]
     acc_ref[...] += _base_dot(x, w_ref, s_ref, per_channel)
-    # per-row A: row m of the tile contracts against its own (bk, r) slice
-    accp_ref[...] += jax.lax.dot_general(
-        x, a_ref[...], (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
+    # per-row A: row m of the tile contracts against its own (bk, r)
+    # slice — unrolled 2-D dots over the bm (= 8) slot rows, since Mosaic
+    # does not lower a batched dot_general
+    for m in range(x.shape[0]):
+        accp_ref[m:m + 1, :] += jax.lax.dot(
+            x[m:m + 1], a_ref[m], preferred_element_type=jnp.float32)
 
     @pl.when(k == k_steps - 1)
     def _epilogue():
@@ -168,7 +168,7 @@ def tt_linear_batched_a(x: jnp.ndarray, w: jnp.ndarray, a: jnp.ndarray,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w, a, b)
@@ -216,7 +216,7 @@ def tt_linear_w8(x: jnp.ndarray, wq: jnp.ndarray, scale: jnp.ndarray,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, wq, scale, a, b)
@@ -263,7 +263,7 @@ def tt_linear_batched_a_w8(x: jnp.ndarray, wq: jnp.ndarray,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, wq, scale, a, b)
@@ -303,7 +303,7 @@ def tt_linear(x: jnp.ndarray, w: jnp.ndarray, a: jnp.ndarray,
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w, a, b)

@@ -189,7 +189,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     mesh = mesh_lib.make_production_mesh(multi_pod=multi_pod)
     t0 = time.time()
     try:
-        with mesh:
+        with jax.set_mesh(mesh):
             rules.set_seq_axis("model" if run.shape.kind != "decode"
                                else None)
             try:

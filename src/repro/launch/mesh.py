@@ -12,16 +12,16 @@ replicated per pod so no inter-pod weight traffic — DESIGN.md §4).
 """
 from __future__ import annotations
 
-import jax
+from repro.sharding.rules import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many (possibly fake) local devices exist —
     used by CPU tests that exercise the sharded code paths."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))

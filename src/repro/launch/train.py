@@ -17,6 +17,7 @@ from repro import configs as registry
 from repro.config.base import OptimizerConfig, RunConfig, SHAPES, TrainConfig
 from repro.core.dmrg import RankSchedule
 from repro.data import LMStream
+from repro.launch import compile_cache
 from repro.train.trainer import Trainer
 
 
@@ -49,6 +50,7 @@ def build_argparser() -> argparse.ArgumentParser:
 
 def main() -> None:
     args = build_argparser().parse_args()
+    compile_cache.enable()
     cfg = (registry.get_config(args.arch) if args.full_config
            else registry.get_smoke_config(args.arch))
     start_rank = args.dmrg_start_rank or args.rank

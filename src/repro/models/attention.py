@@ -114,7 +114,7 @@ def attention(x: jnp.ndarray, w: dict, ctx: AdapterCtx, cfg: ModelConfig, *,
     encoder output; cache then holds precomputed k/v and is not updated).
     Decode mode when ``cache is not None and x.shape[1] == 1`` for self-attn.
     Paged mode when ``block_tables`` is given: ``cache`` holds flat
-    (N, page, KV, hd) block pools and x is a (B, C) chunk of co-batched
+    (N, KV, page, hd) block pools and x is a (B, C) chunk of co-batched
     decode/prefill tokens (see _paged_attend).
     """
     hd = cfg.resolved_head_dim
@@ -171,11 +171,14 @@ def attention(x: jnp.ndarray, w: dict, ctx: AdapterCtx, cfg: ModelConfig, *,
         v = serve_tp_slice(v, 2)
         kv_l = k.shape[2]
         h_l = kv_l * g
+        # the cache is head-major, (B, KV, S, hd), like the paged pools
         if jnp.ndim(cache_pos) == 0 and t == 1:
             ck = jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, cache_pos, 0, 0))
+                cache["k"], k.transpose(0, 2, 1, 3).astype(cache["k"].dtype),
+                (0, 0, cache_pos, 0))
             cv = jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, cache_pos, 0, 0))
+                cache["v"], v.transpose(0, 2, 1, 3).astype(cache["v"].dtype),
+                (0, 0, cache_pos, 0))
         else:
             rows = jnp.arange(b)
             cp0 = jnp.broadcast_to(jnp.asarray(cache_pos), (b,))
@@ -183,13 +186,13 @@ def attention(x: jnp.ndarray, w: dict, ctx: AdapterCtx, cfg: ModelConfig, *,
             for j in range(t):
                 # mode="drop": columns past the cache end (a draft chunk
                 # overhanging cache_len) discard instead of clamping
-                ck = ck.at[rows, cp0 + j].set(
+                ck = ck.at[rows, :, cp0 + j].set(
                     k[:, j].astype(ck.dtype), mode="drop")
-                cv = cv.at[rows, cp0 + j].set(
+                cv = cv.at[rows, :, cp0 + j].set(
                     v[:, j].astype(cv.dtype), mode="drop")
-        ck = maybe_shard(ck, BATCH, "model", None, None)
-        cv = maybe_shard(cv, BATCH, "model", None, None)
-        s_len = ck.shape[1]
+        ck = maybe_shard(ck, BATCH, None, "model", None)
+        cv = maybe_shard(cv, BATCH, None, "model", None)
+        s_len = ck.shape[2]
         cp = jnp.broadcast_to(jnp.asarray(cache_pos), (b,))
         cols = []
         for j in range(t):
@@ -202,7 +205,11 @@ def attention(x: jnp.ndarray, w: dict, ctx: AdapterCtx, cfg: ModelConfig, *,
                 qh = q[:, j:j + 1].reshape(b, 1, kv_l, g, hd)
                 mask = (jnp.arange(s_len)[None, :] <= (cp + j)[:, None]
                         )[:, None, None, None, :]
-                cols.append(_softmax_attend(qh, ck, cv, mask, scale))
+                s = jnp.einsum("btkgh,bksh->bkgts", qh, ck,
+                               preferred_element_type=jnp.float32) * scale
+                p = jax.nn.softmax(jnp.where(mask, s, NEG_INF), axis=-1)
+                cols.append(jnp.einsum("bkgts,bksh->btkgh",
+                                       p.astype(cv.dtype), cv))
         out = cols[0] if t == 1 else jnp.concatenate(
             [c.reshape(b, 1, kv_l, g, hd) for c in cols], axis=1)
         out = out.reshape(b, t, h_l, hd)
@@ -258,7 +265,10 @@ def attention(x: jnp.ndarray, w: dict, ctx: AdapterCtx, cfg: ModelConfig, *,
         if cache is not None and kv_x is not None and new_cache is None:
             new_cache = {"k": k, "v": v}     # prefill of a cross cache
         elif kv_x is None and cache is None and new_cache is None:
-            new_cache = {"k": k, "v": v}     # prefill returns cache to caller
+            # prefill returns the cache to the caller, head-major like
+            # init_cache
+            new_cache = {"k": k.transpose(0, 2, 1, 3),
+                         "v": v.transpose(0, 2, 1, 3)}
 
     # row-parallel: out still carries only this shard's head group —
     # contiguous head slices align with contiguous wo rows, so the
@@ -279,7 +289,8 @@ def _paged_attend(x, q, k, v, w, ctx: AdapterCtx, cache: dict,
     x: (B, C, d_model) — C co-batched tokens per slot (decode: 1 real
     token; chunked prefill: up to C prompt tokens), token c of slot b at
     absolute position positions[b, c]; q/k/v: projected+RoPE'd heads;
-    cache: {"k","v"} (N, page, KV, hd) pools shared by every slot;
+    cache: {"k","v"} (N, KV, page, hd) head-major pools shared by every
+    slot (the paged kernel's layout: each block's last two dims are whole);
     block_tables: (B, P) int32, sentinel >= N for unallocated pages.
 
     Write-then-attend: a token's own k/v lands in its cell before the
@@ -309,7 +320,7 @@ def _paged_attend(x, q, k, v, w, ctx: AdapterCtx, cache: dict,
     q = serve_tp_slice(q, 2)
     k = serve_tp_slice(k, 2)
     v = serve_tp_slice(v, 2)
-    n_blocks, page = cache["k"].shape[0], cache["k"].shape[1]
+    n_blocks, page = cache["k"].shape[0], cache["k"].shape[2]
     p_tab = block_tables.shape[1]
     pidx = positions // page                                 # (B, C)
     blk = jnp.take_along_axis(block_tables,
@@ -320,15 +331,20 @@ def _paged_attend(x, q, k, v, w, ctx: AdapterCtx, cache: dict,
     if quantized:
         k, k_s = quant_lib.quantize_kv(k)
         v, v_s = quant_lib.quantize_kv(v)
-    ck = cache["k"].at[blk, off].set(k.astype(cache["k"].dtype),
-                                     mode="drop")
-    cv = cache["v"].at[blk, off].set(v.astype(cache["v"].dtype),
-                                     mode="drop")
+    # cell (blk, :, off) of a (N, KV, page, ...) pool takes token (b, c)'s
+    # (KV, ...) heads
+    ck = cache["k"].at[blk, :, off].set(k.astype(cache["k"].dtype),
+                                        mode="drop")
+    cv = cache["v"].at[blk, :, off].set(v.astype(cache["v"].dtype),
+                                        mode="drop")
     new_cache = {"k": ck, "v": cv}
     scales = {}
     if quantized:
-        new_cache["k_s"] = cache["k_s"].at[blk, off].set(k_s, mode="drop")
-        new_cache["v_s"] = cache["v_s"].at[blk, off].set(v_s, mode="drop")
+        # scale pools are (N, KV, 1, page): cell (blk, :, 0, off)
+        new_cache["k_s"] = cache["k_s"].at[blk, :, 0, off].set(
+            k_s, mode="drop")
+        new_cache["v_s"] = cache["v_s"].at[blk, :, 0, off].set(
+            v_s, mode="drop")
         scales = dict(k_scale=new_cache["k_s"], v_scale=new_cache["v_s"])
     pol = ctx.policy if _flash_ok(ctx) else None
     out = dispatch.paged_decode_attention(q, ck, cv, block_tables,
@@ -347,21 +363,26 @@ def _paged_attend(x, q, k, v, w, ctx: AdapterCtx, cache: dict,
 
 
 def init_cache(cfg: ModelConfig, batch: int, length: int, dtype) -> dict:
+    """Dense self-attention KV cache, head-major (B, KV, S, hd) like the
+    paged pools: the decode kernel reads whole (S-tile, hd) blocks."""
     hd = cfg.resolved_head_dim
-    shape = (batch, length, cfg.num_kv_heads, hd)
+    shape = (batch, cfg.num_kv_heads, length, hd)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, page_size: int,
                      dtype, kv_quant: bool = False) -> dict:
-    """Flat per-layer KV block pool: (num_blocks, page, KV, hd). Which
-    request owns which block lives host-side (serving/block_manager.py).
-    ``kv_quant`` stores cells as int8 plus per-cell f32 scale pools
-    (``k_s``/``v_s``, (num_blocks, page, KV)) in the same block layout."""
+    """Flat per-layer KV block pool: (num_blocks, KV, page, hd), head-major
+    so the paged kernel DMAs one whole (page, hd) tile per (block, head).
+    Which request owns which block lives host-side
+    (serving/block_manager.py). ``kv_quant`` stores cells as int8 plus
+    per-cell f32 scale pools (``k_s``/``v_s``, (num_blocks, KV, 1, page):
+    one (1, page) row per block and head, the tile the paged kernel reads)
+    in the same block layout."""
     hd = cfg.resolved_head_dim
-    shape = (num_blocks, page_size, cfg.num_kv_heads, hd)
+    shape = (num_blocks, cfg.num_kv_heads, page_size, hd)
     if kv_quant:
-        s_shape = shape[:-1]
+        s_shape = (num_blocks, cfg.num_kv_heads, 1, page_size)
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
                 "k_s": jnp.zeros(s_shape, jnp.float32),

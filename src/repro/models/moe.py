@@ -37,7 +37,6 @@ from jax.sharding import PartitionSpec as P
 from repro.config.base import ModelConfig
 from repro.models.layers import AdapterCtx, adapted_linear, dense_ffn
 from repro.sharding import batch_axes, current_mesh
-from repro.sharding.compat import shard_map
 
 
 def _router(x, w_router, n_k):
@@ -210,7 +209,7 @@ def moe_ffn(x: jnp.ndarray, w: dict, ctx: AdapterCtx, cfg: ModelConfig):
                              wg_l, wu_l, wd_l, ctx_l, cfg)
             return jax.lax.psum(y_l, "model")
 
-        y = shard_map(
+        y = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(bspec, None), P(bspec, None), P(bspec, None),
                       wg_spec, wg_spec, wd_spec, adapter_specs),

@@ -398,7 +398,7 @@ def init_caches(cfg: ModelConfig, batch: int, length: int, dtype, *,
 def init_paged_caches(cfg: ModelConfig, num_blocks: int, page_size: int,
                       dtype, kv_quant: bool = False, *,
                       num_super_blocks: Optional[int] = None) -> list:
-    """Paged cache pytree: one flat (nb, num_blocks, page, KV, hd) block
+    """Paged cache pytree: one flat (nb, num_blocks, KV, page, hd) block
     pool per pattern position. Attention-only — the paged engine rejects
     stateful mixers up front (their caches are not position-indexed).
     ``kv_quant`` makes the pools int8 with per-cell scale pools riding in
@@ -426,7 +426,7 @@ def init_paged_caches(cfg: ModelConfig, num_blocks: int, page_size: int,
 def copy_cache_block(caches, src, dst):
     """Device-side copy-on-write: duplicate physical block ``src`` into
     ``dst`` across every layer of a paged cache pytree (leaves stacked
-    (nb, N, page, KV, hd)). ``src``/``dst`` may be traced scalars; the
+    (nb, N, KV, page, hd)). ``src``/``dst`` may be traced scalars; the
     host-side BlockManager decides when a copy is needed
     (serving/block_manager.py). A ``dst`` >= N drops the write — the
     data-striped engine passes the sentinel on replicas that do not own

@@ -127,7 +127,6 @@ from repro.serving.stats import EngineStats
 from repro.sharding import (serve_cache_pspec, serve_cache_sharding,
                             serve_dp_index, serve_mesh, serve_tp_slice,
                             set_serve_dp, set_serve_rp, set_serve_tp)
-from repro.sharding.compat import shard_map
 
 
 @dataclasses.dataclass
@@ -225,7 +224,7 @@ class PagedState(NamedTuple):
     out: jnp.ndarray        # (B, out_cap) generated tokens
     task: jnp.ndarray       # (B,)    per-slot task id (4+1d routing)
     key: jnp.ndarray        # PRNG key (in-graph sampling)
-    caches: Any             # paged KV pools (leaves (nb, N, page, KV, hd))
+    caches: Any             # paged KV pools (leaves (nb, N, KV, page, hd))
     dcaches: Any = None     # drafter KV pools, same block tables
     steps: Any = 0          # loop iterations (engine steps)
     drafted: Any = 0        # drafter tokens proposed
@@ -260,7 +259,7 @@ class Engine:
                  sampling: sampling_lib.SamplingConfig =
                  sampling_lib.SamplingConfig(),
                  seed: int = 0,
-                 kernels: Optional[KernelConfig] = None,
+                 kernels: KernelConfig = KernelConfig(),
                  serve: Optional[ServeConfig] = None):
         for mixer, _ in model_cfg.block_pattern:
             if mixer != "attn":
@@ -502,12 +501,12 @@ class Engine:
                 set_serve_rp(False)
                 set_serve_dp(None)
 
-        return shard_map(traced, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+        return jax.shard_map(traced, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def _init_dense(self) -> None:
         """Jit (and, on a mesh, shard_map) the dense-mode step graphs.
-        Sharded layout: decode caches (nb, B, S, KV, hd) shard the
+        Sharded layout: decode caches (nb, B, KV, S, hd) shard the
         kv-head axis on "model"; prefill stays a plain replicated jit
         (it computes full-width caches that admit slices per shard).
         With speculation the drafter weights ride as three extra leading
@@ -864,12 +863,12 @@ class Engine:
         key, sub = jax.random.split(state.key)
         t0 = sampling_lib.sample(last_logits[None], sub, self.sampling)[0]
         caches1 = jax.tree_util.tree_map(
-            lambda c: serve_tp_slice(c, 3), caches1)
+            lambda c: serve_tp_slice(c, 2), caches1)
         caches = transformer.insert_cache_slot(state.caches, caches1, slot)
         dcaches = state.dcaches
         if self._spec_on:
             dcaches1 = jax.tree_util.tree_map(
-                lambda c: serve_tp_slice(c, 3), dcaches1)
+                lambda c: serve_tp_slice(c, 2), dcaches1)
             dcaches = transformer.insert_cache_slot(state.dcaches, dcaches1,
                                                     slot)
         return state._replace(

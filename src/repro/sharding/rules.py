@@ -24,13 +24,23 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.interpreters import pxla
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
-def current_mesh() -> Mesh | None:
-    mesh = pxla.thread_resources.env.physical_mesh
-    if mesh is None or mesh.empty:
+def auto_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with every axis in Auto (GSPMD) mode: the rules
+    here place arrays by sharding constraints, not by explicit axis types
+    (the installed JAX defaults ``make_mesh`` to Explicit)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
+def current_mesh():
+    """The ambient mesh entered with ``jax.set_mesh`` (an ``AbstractMesh``,
+    readable inside ``jit``), or None without one. Inside a ``shard_map``
+    region every axis is manual and there is no mesh left to constrain
+    against, so that counts as None too."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.are_all_axes_manual:
         return None
     return mesh
 
@@ -194,17 +204,17 @@ def serve_mesh(shape, axes: tuple = ("data", "model")) -> Mesh:
             f"but only {have} are visible (on CPU force fake devices "
             f"with XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{need})")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def serve_cache_pspec(caches, axis: str = "model",
                       dp_axis: str | None = None):
     """PartitionSpec pytree sharding serving KV caches on the KV-HEAD
-    axis — axis 3 of every leaf in both cache layouts:
+    axis, axis 2 of every leaf (all of them head-major):
 
-      paged pools   (nb, num_blocks, page, KV, hd)  k / v
-      scale pools   (nb, num_blocks, page, KV)      k_s / v_s (int8 KV)
-      dense caches  (nb, B, S, KV, hd)              k / v
+      paged pools   (nb, num_blocks, KV, page, hd)  k / v
+      scale pools   (nb, num_blocks, KV, 1, page)   k_s / v_s (int8 KV)
+      dense caches  (nb, B, KV, S, hd)              k / v
 
     Page/block/sequence dims stay whole, so one host-side block id
     indexes every shard's pool identically (the BlockManager never needs
@@ -214,7 +224,7 @@ def serve_cache_pspec(caches, axis: str = "model",
     LOCAL block ids its per-replica BlockManager hands out."""
     def one(leaf):
         spec = [None] * leaf.ndim
-        spec[3] = axis
+        spec[2] = axis
         if dp_axis is not None:
             spec[1] = dp_axis
         return P(*spec)
@@ -346,8 +356,8 @@ def cache_spec_for(path: str, shape) -> P:
     """
     leaf = path.split("/")[-1]
     nd = len(shape)
-    if leaf in ("k", "v") and nd == 5:       # (nb, B, S, KV, hd)
-        return P(None, BATCH, "model", None, None)
+    if leaf in ("k", "v") and nd == 5:       # (nb, B, KV, S, hd)
+        return P(None, BATCH, None, "model", None)
     if leaf == "h" and nd == 4:              # (nb, B, di, ds) mamba state
         return P(None, BATCH, "model", None)
     if leaf == "conv" and nd == 4:           # (nb, B, K-1, di)
@@ -400,7 +410,8 @@ def reshard_after_reshape(tree, mesh: Mesh | None = None):
     replicate), ensuring each device holds the rank-changed arrays before
     the next jitted train step retraces against them. No-op without an
     ambient mesh (single-device training and unit tests)."""
-    mesh = mesh or current_mesh()
+    if mesh is None and current_mesh() is not None:
+        mesh = jax.sharding.get_mesh()       # host side: the concrete mesh
     if mesh is None:
         return tree
     return jax.device_put(tree, tree_sharding(tree, mesh, spec_for_param))

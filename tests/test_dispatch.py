@@ -233,8 +233,8 @@ def test_ops_decode_attention_matches_ref():
     b, s, h, kv, d = 3, 40, 4, 2, 32
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (b, 1, h, d))
-    k = jax.random.normal(ks[1], (b, s, kv, d))
-    v = jax.random.normal(ks[2], (b, s, kv, d))
+    k = jax.random.normal(ks[1], (b, kv, s, d))    # head-major caches
+    v = jax.random.normal(ks[2], (b, kv, s, d))
     pos = jnp.array([0, 7, 39])     # includes a fresh slot and a full cache
     y = ops.decode_attention(q, k, v, pos, backend="pallas",
                              interpret=True)

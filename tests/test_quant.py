@@ -230,10 +230,11 @@ def test_int8_kv_paged_attention_kernel_matches_ref(c, heads):
     b, d, n, page, p_tab = 3, 16, 12, 8, 4
     ks = jax.random.split(jax.random.PRNGKey(c), 3)
     q = jax.random.normal(ks[0], (b, c, h, d), jnp.float32)
-    kc = jax.random.normal(ks[1], (n, page, kv, d), jnp.float32)
-    vc = jax.random.normal(ks[2], (n, page, kv, d), jnp.float32)
+    kc = jax.random.normal(ks[1], (n, kv, page, d), jnp.float32)
+    vc = jax.random.normal(ks[2], (n, kv, page, d), jnp.float32)
     kq, k_s = quant.quantize_kv(kc)
     vq, v_s = quant.quantize_kv(vc)
+    k_s, v_s = k_s[:, :, None], v_s[:, :, None]   # (N, KV, 1, page) pools
     tables = np.full((b, p_tab), n, np.int32)     # sentinel everywhere
     tables[0, :3] = [2, 7, 1]
     tables[1, :2] = [4, 9]

@@ -137,7 +137,7 @@ def test_tp4_int8_kv_and_weights_parity():
     # scale pools are physically sharded alongside the int8 cells
     ks = eng._paged_caches[0]["self"]["k_s"]
     local = ks.addressable_shards[0].data.shape
-    assert local[3] == ks.shape[3] // 4
+    assert local[2] == ks.shape[2] // 4      # (nb, N, KV, 1, page)
 
 
 @needs4
@@ -197,7 +197,7 @@ def test_tp4_stats_per_shard_sums_to_global():
     # device truth: each shard holds a 1/4 kv-head stripe of every pool
     for leaf in jax.tree_util.tree_leaves(eng4._paged_caches):
         local = leaf.addressable_shards[0].data.shape
-        assert local[3] == leaf.shape[3] // 4, (leaf.shape, local)
+        assert local[2] == leaf.shape[2] // 4, (leaf.shape, local)
 
 
 def test_mesh_validation_errors():

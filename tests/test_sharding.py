@@ -50,7 +50,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
                              params['frozen'], {'tokens': toks}, cfg, spec)
         l_ref = m_ref['ce']
         mesh = make_host_mesh(4, 2)
-        with mesh:
+        with jax.set_mesh(mesh):
             state = ts.init_train_state(params['adapter'],
                                         GradCompressor('none'))
             step = ts.make_train_step(cfg, spec, run.optimizer, run.train,
@@ -86,7 +86,7 @@ def test_moe_ep_matches_local_path():
              'e_wd': jax.random.normal(jax.random.PRNGKey(2), (4, 8, 16))}
         y_local, _ = MO.moe_ffn(x, w, NO_ADAPTER, cfg)
         mesh = make_host_mesh(2, 4)   # model axis 4 -> 1 expert per shard
-        with mesh:
+        with jax.set_mesh(mesh):
             y_ep, _ = jax.jit(lambda x, w: MO.moe_ffn(x, w, NO_ADAPTER,
                                                       cfg))(x, w)
         err = float(jnp.abs(y_local - y_ep).max())
@@ -125,15 +125,14 @@ def test_compressed_psum_shard_map():
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_host_mesh
         from repro.distributed import compressed_psum
-        from repro.sharding.compat import shard_map
         mesh = make_host_mesh(8, 1)
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
         def f(xs):
             exact = jax.lax.psum(xs, 'data')
             approx = compressed_psum(xs, 'data', kind='int8')
             return exact, approx
-        with mesh:
-            ex, ap = jax.jit(shard_map(
+        with jax.set_mesh(mesh):
+            ex, ap = jax.jit(jax.shard_map(
                 f, mesh=mesh, in_specs=P('data', None),
                 out_specs=(P(None, None), P(None, None)),
                 check_vma=False))(x)

@@ -185,7 +185,7 @@ def test_dmrg_training_under_forced_mesh(tmp_path):
                         seed=11, branching=2)
         mesh = Mesh(np.array(jax.devices()).reshape(2, 2),
                     ('data', 'model'))
-        with mesh:
+        with jax.set_mesh(mesh):
             tr = Trainer(run=run, data=data, total_steps=15,
                          steps_per_epoch=10,
                          rank_schedule=RankSchedule(milestones=((1, 6),)))
