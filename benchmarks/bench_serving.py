@@ -32,7 +32,7 @@ Throughput figures always come from a SECOND ``generate`` call — the
 first, traced call pays jit compilation and is excluded from every
 ``tokens_per_s`` wall. Paged rows also report the latency phase split
 (``ttft_ms`` time-to-first-token vs ``tpot_ms`` per-token decode
-latency) straight from engine.last_stats.
+latency, p50 over the requests' own times in engine.last_results).
 
 Engine stats of every engine run land in ``ENGINE_STATS`` (reset per
 ``run()``) so ``benchmarks/run.py --json`` can emit them machine-readably.
@@ -60,6 +60,7 @@ from repro.models import model as M, transformer as T
 from repro.peft import api as peft_api
 from repro.serving import AdapterRuntime, Engine, Request
 from repro.serving import engine as se
+from repro.serving.stats import latency_quantiles
 
 #: engine stats (dataclasses.asdict + derived rates) of every timed engine
 #: run in the latest run() call, labeled — consumed by run.py --json
@@ -72,10 +73,18 @@ TOKEN_MATCH_MIN = 0.9
 
 def _record_stats(label: str, st) -> None:
     d = dataclasses.asdict(st)
+    d.pop("decode_call_log")            # one tuple per call: too long
     d.update(label=label, tokens_per_s=st.tokens_per_s,
              prefix_hit_rate=st.prefix_hit_rate,
              kv_bytes_peak=st.kv_bytes_peak)
     ENGINE_STATS.append(d)
+
+
+def _p50_ms(eng, name: str) -> str:
+    """Median ``ttft`` or ``tpot`` of the engine's last generate call, in
+    ms, from each request's own times."""
+    v = latency_quantiles(eng.last_results).get(f"{name}_p50")
+    return "nan" if v is None else f"{v * 1e3:.2f}"
 
 
 def _decode_step_rows(rows) -> None:
@@ -288,8 +297,8 @@ def _paged_rows(rows, *, smoke: bool) -> None:
             f"serving/engine_{mode}_shared_prefix", dt / max(
                 st.tokens_generated, 1) * 1e6,
             f"tok_per_s={st.tokens_per_s:.1f},"
-            f"ttft_ms={st.ttft_s * 1e3:.1f},"
-            f"tpot_ms={st.tpot_s * 1e3:.2f},"
+            f"ttft_ms={_p50_ms(eng, 'ttft')},"
+            f"tpot_ms={_p50_ms(eng, 'tpot')},"
             f"kv_bytes_peak={st.kv_bytes_peak},"
             f"kv_blocks_peak={st.kv_blocks_peak}/{st.num_blocks},"
             f"prefix_hit_rate={st.prefix_hit_rate:.2f},"
@@ -297,7 +306,7 @@ def _paged_rows(rows, *, smoke: bool) -> None:
             f"decode_traces={st.decode_traces},"
             f"prefill_traces={st.prefill_traces}"))
         _record_stats(f"engine_{mode}_shared_prefix", st)
-        print(f"# engine stats [{mode}]: {st.summary()}")
+        print(f"# engine stats [{mode}]: {st.summary(eng.last_results)}")
         if mode == "dense":
             dense_bytes = st.kv_bytes_peak   # the engine's own number
         if mode == "paged":
@@ -375,7 +384,7 @@ def _quant_rows(rows, *, smoke: bool) -> None:
             f"block_bytes={st.block_bytes},"
             f"prefix_hit_rate={st.prefix_hit_rate:.2f}"))
         _record_stats(f"engine_paged_{name}", st)
-        print(f"# engine stats [{name}]: {st.summary()}")
+        print(f"# engine stats [{name}]: {st.summary(eng.last_results)}")
     total = sum(len(o) for o in outs["fp"])
     same = sum(int(a == b) for f, q in zip(outs["fp"], outs["int8"])
                for a, b in zip(f.tolist(), q.tolist()))
@@ -455,7 +464,7 @@ def _mesh_rows(rows, *, smoke: bool, mesh_shape=(1, 4)) -> None:
             f"kv_bytes_peak_per_shard={st.kv_bytes_peak_per_shard},"
             f"prefix_hit_rate={st.prefix_hit_rate:.2f}"))
         _record_stats(f"engine_{label}", st)
-        print(f"# engine stats [{label}]: {st.summary()}")
+        print(f"# engine stats [{label}]: {st.summary(eng.last_results)}")
     t1, t4 = stats["tp1"], stats[f"tp{tp}"]
     parity = all(a.tolist() == b.tolist() for a, b in
                  zip(outs["tp1"], outs[f"tp{tp}"]))
@@ -533,12 +542,12 @@ def _fleet_rows(rows, *, smoke: bool, mesh_shape=(2, 4)) -> None:
             f"serving/engine_fleet_{label}",
             dt / max(st.tokens_generated, 1) * 1e6,
             f"tok_per_s={st.tokens_per_s:.1f},dp={st.data_shards},"
-            f"shards={st.shards},ttft_ms={st.ttft_s * 1e3:.1f},"
-            f"tpot_ms={st.tpot_s * 1e3:.2f},"
+            f"shards={st.shards},ttft_ms={_p50_ms(eng, 'ttft')},"
+            f"tpot_ms={_p50_ms(eng, 'tpot')},"
             f"kv_bytes_peak={st.kv_bytes_peak},"
             f"kv_blocks_peak={st.kv_blocks_peak}/{st.num_blocks}"))
         _record_stats(f"engine_fleet_{label}", st)
-        print(f"# engine stats [{label}]: {st.summary()}")
+        print(f"# engine stats [{label}]: {st.summary(eng.last_results)}")
     d1, d2 = stats["dp1"], stats[f"dp{dp}"]
     parity = all(a.tolist() == b.tolist() for a, b in
                  zip(outs["dp1"], outs[f"dp{dp}"]))
@@ -552,8 +561,8 @@ def _fleet_rows(rows, *, smoke: bool, mesh_shape=(2, 4)) -> None:
         f"replica_block_budget={per_replica_blocks},"
         f"tok_per_s_dp1={d1.tokens_per_s:.1f},"
         f"tok_per_s_dp{dp}={d2.tokens_per_s:.1f},"
-        f"ttft_ms_dp{dp}={d2.ttft_s * 1e3:.1f},"
-        f"tpot_ms_dp{dp}={d2.tpot_s * 1e3:.2f}"))
+        f"ttft_ms_dp{dp}={_p50_ms(eng, 'ttft')},"
+        f"tpot_ms_dp{dp}={_p50_ms(eng, 'tpot')}"))
     if not parity:
         raise AssertionError("dp-striped engine diverged from dp1")
     if len(reps) != dp or sorted(r["replica"] for r in reps) != list(
@@ -636,7 +645,7 @@ def _multitask_rows(rows, *, smoke: bool) -> None:
             f"adapter_evictions={st.adapter_evictions},"
             f"decode_traces={st.decode_traces}"))
         _record_stats(f"engine_multitask_{label}", st)
-        print(f"# engine stats [{label}]: {st.summary()}")
+        print(f"# engine stats [{label}]: {st.summary(eng.last_results)}")
     full, pool = stats["all_resident"], stats[f"pool{n_slots}"]
     parity = all(a.tolist() == b.tolist() for a, b in
                  zip(outs["all_resident"], outs[f"pool{n_slots}"]))
@@ -761,7 +770,7 @@ def _spec_rows(rows, *, smoke: bool) -> None:
             f"tok_per_step={st.tokens_per_step:.2f},"
             f"decode_traces={st.decode_traces}"))
         _record_stats(f"engine_{label}_spec_workload", st)
-        print(f"# engine stats [{label}]: {st.summary()}")
+        print(f"# engine stats [{label}]: {st.summary(eng.last_results)}")
     parity = all(a.tolist() == b.tolist() for a, b in
                  zip(outs["base"], outs["spec"]))
     accept = stats["spec"].acceptance_rate
@@ -847,7 +856,7 @@ def _chaos_rows(rows, *, smoke: bool) -> None:
         f"failed={st.failed_requests},waits={st.backpressure_waits},"
         f"decode_traces={st.decode_traces}"))
     _record_stats("engine_chaos_survivors", st)
-    print(f"# engine stats [chaos]: {st.summary()}")
+    print(f"# engine stats [chaos]: {st.summary(eng.last_results)}")
     if not identical:
         raise AssertionError(
             "chaos perturbed a survivor's tokens — scheduling faults "

@@ -67,7 +67,7 @@ def serve(cfg, runtime, reqs, *, max_batch, cache_len, out_cap, tp=0,
     # per-generate observability: KV blocks in use, prefix-cache hit rate,
     # admit/evict/COW counts (serving/stats.py)
     st = eng.last_stats
-    print(f"  stats: {st.summary()}")
+    print(f"  stats: {st.summary(eng.last_results)}")
     if st.data_shards > 1 or disagg:
         # per-replica placement/pressure figures (replica -1 is the
         # dedicated prefill worker under --disagg)

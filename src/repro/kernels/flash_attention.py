@@ -109,6 +109,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         kv_steps=grid[2], kv_len=kv_len or s_len)
     return pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -148,6 +149,7 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         kv_steps=grid[2], kv_len=kv_len or s_len, with_stats=True)
     return pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -297,6 +299,7 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     rowspec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, kv_steps=s_len // bkv, **common),
+        name="flash_attention_bwd_dq",
         grid=(bh, t // bq, s_len // bkv),
         in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
         out_specs=qspec,
@@ -312,6 +315,7 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     rowspec2 = pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, i))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, q_steps=t // bq, **common),
+        name="flash_attention_bwd_dkv",
         grid=(bh, s_len // bkv, t // bq),
         in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
         out_specs=[kspec2, kspec2],
@@ -419,6 +423,7 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     )
     out = pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, d), q.dtype),
         compiler_params=pltpu.CompilerParams(

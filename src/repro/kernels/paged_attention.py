@@ -168,6 +168,7 @@ def paged_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     )
     return pl.pallas_call(
         kernel,
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, c, d), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -155,6 +155,7 @@ def tt_linear_batched_a(x: jnp.ndarray, w: jnp.ndarray, a: jnp.ndarray,
                                k_steps=grid[2])
     return pl.pallas_call(
         kernel,
+        name="tt_linear_batched_a",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
@@ -202,6 +203,7 @@ def tt_linear_w8(x: jnp.ndarray, wq: jnp.ndarray, scale: jnp.ndarray,
                                per_channel=per_channel)
     return pl.pallas_call(
         kernel,
+        name="tt_linear_w8",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
@@ -249,6 +251,7 @@ def tt_linear_batched_a_w8(x: jnp.ndarray, wq: jnp.ndarray,
                                k_steps=grid[2], per_channel=per_channel)
     return pl.pallas_call(
         kernel,
+        name="tt_linear_batched_a_w8",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
@@ -290,6 +293,7 @@ def tt_linear(x: jnp.ndarray, w: jnp.ndarray, a: jnp.ndarray,
     kernel = functools.partial(_kernel, alpha=alpha, k_steps=grid[2])
     return pl.pallas_call(
         kernel,
+        name="tt_linear",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

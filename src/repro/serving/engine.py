@@ -107,6 +107,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import tracing
 from repro.checkpoint import ckpt as ckpt_lib
 from repro.config.base import (KernelConfig, ModelConfig, QuantConfig,
                                ServeConfig)
@@ -160,11 +161,23 @@ class RequestResult(NamedTuple):
     (``engine.last_results``). ``tokens`` holds everything the request
     emitted — possibly fewer than max_new_tokens when it was cancelled,
     timed out or failed; FAILED requests (in-graph NaN/inf logit
-    detection) keep the tokens emitted BEFORE the fault."""
+    detection) keep the tokens emitted BEFORE the fault.
+
+    Times are ``time.perf_counter()`` seconds: ``t_submit`` the
+    ``generate`` entry, ``t_admit`` the first admission into a slot,
+    ``t_first`` the first token, ``t_done`` the terminal status (None
+    where the request never got that far). In paged mode ``t_first`` is
+    placed inside the decode call that produced it, by the loop step that
+    emitted it (``PagedState.first_step``) between the call's dispatch and
+    its return; in dense mode the first token is sampled at admission."""
     tokens: np.ndarray
     status: str
     n_generated: int
     preemptions: int = 0
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_first: Optional[float] = None
+    t_done: Optional[float] = None
 
 
 def _pad_caches(caches, cfg: ModelConfig, batch: int, cache_len: int,
@@ -230,6 +243,8 @@ class PagedState(NamedTuple):
     drafted: Any = 0        # drafter tokens proposed
     accepted: Any = 0       # drafter tokens accepted by the verifier
     failed: Any = None      # (B,) bool: in-graph NaN guard tripped
+    first_step: Any = None  # (B,) int32: ``steps`` after the iteration
+    #                         that emitted the slot's first token (-1 before)
 
 
 class Engine:
@@ -636,7 +651,7 @@ class Engine:
             tok=sl, prompt=sl, plen=sl, done=sl, remaining=sl,
             active=sl, widx=sl, out=sl, task=sl, key=sl,
             caches=cspec, dcaches=dspec,
-            steps=sl, drafted=sl, accepted=sl, failed=sl)
+            steps=sl, drafted=sl, accepted=sl, failed=sl, first_step=sl)
         wspec = tuple(self._rep_spec(w) for w in self._step_weights)
         self._padmit = jax.jit(self._shard_mapped(
             self._paged_admit_impl,
@@ -1125,7 +1140,8 @@ class Engine:
                                     mode="drop"),
             tok=state.tok.at[ls, 0].set(tok0, mode="drop"),
             task=state.task.at[ls].set(task_id, mode="drop"),
-            failed=state.failed.at[ls].set(False, mode="drop"))
+            failed=state.failed.at[ls].set(False, mode="drop"),
+            first_step=state.first_step.at[ls].set(-1, mode="drop"))
 
     def _kill_dense_impl(self, state: DecodeState, slot) -> DecodeState:
         """Abort one dense slot between loop exits: mark it dead in-graph
@@ -1222,6 +1238,12 @@ class Engine:
         oob = jnp.int32(self._p_tab * self._page)
         cond = self._loop_cond(active0)
 
+        def stamp_first(s, emitted):
+            """``first_step`` of the slots whose first token this
+            iteration emitted: the step count after it."""
+            return jnp.where(emitted & (s.widx == 0), s.steps + 1,
+                             s.first_step)
+
         def body(s):
             is_pf = s.done < s.plen
             start = jnp.where(is_pf, s.done, 0)
@@ -1232,37 +1254,43 @@ class Engine:
             dec = jnp.pad(s.tok, ((0, 0), (0, C - 1)))
             toks = jnp.where(is_pf[:, None], chunk, dec)
             task = s.task if self.rt.tasked else None
-            logits, caches = transformer.paged_step(
-                base, self.cfg, self.rt.spec, bc, pl, toks, s.caches,
-                tables, s.done, ntok - 1, task=task, policy=self.policy)
-            # NaN guard (DESIGN.md §13): chaos poisons injected rows,
-            # then ANY non-finite logit row stops emitting and raises
-            # its failed flag for the host to fail the request
-            inject = s.active & (nan_at >= 0) & (s.widx >= nan_at)
-            logits = jnp.where(inject[:, None], jnp.nan, logits)
-            bad = s.active & ~jnp.all(jnp.isfinite(logits), axis=-1)
-            key, sub = jax.random.split(self._key_of(s))
-            pm = (sampling_lib.history_mask(s.out, s.widx, V)
-                  if rp_on else None)
-            nxt = sampling_lib.sample(logits, sub, self.sampling,
-                                      penalty_mask=pm)
-            new_done = s.done + ntok
-            # a slot emits a token when its step reached the last prompt
-            # position (prefill -> first token) or is decoding
-            produced = s.active & (new_done >= s.plen) & ~bad
-            col = jnp.where(produced, s.widx, self.out_cap)
-            out = s.out.at[rows, col].set(nxt, mode="drop")
-            adv = produced.astype(jnp.int32)
-            tok = jnp.where(produced[:, None], nxt[:, None], s.tok)
-            return PagedState(
-                tok=tok, prompt=s.prompt, plen=s.plen, done=new_done,
-                remaining=s.remaining - adv,
-                active=s.active & ((s.remaining > 1) | ~produced) & ~bad,
-                widx=s.widx + adv, out=out, task=s.task,
-                key=self._wrap_key(key), caches=caches,
-                dcaches=s.dcaches, steps=s.steps + 1,
-                drafted=s.drafted, accepted=s.accepted,
-                failed=s.failed | bad)
+            with jax.named_scope("model"):
+                logits, caches = transformer.paged_step(
+                    base, self.cfg, self.rt.spec, bc, pl, toks, s.caches,
+                    tables, s.done, ntok - 1, task=task,
+                    policy=self.policy)
+            with jax.named_scope("sample"):
+                # NaN guard (DESIGN.md §13): chaos poisons injected rows,
+                # then ANY non-finite logit row stops emitting and raises
+                # its failed flag for the host to fail the request
+                inject = s.active & (nan_at >= 0) & (s.widx >= nan_at)
+                logits = jnp.where(inject[:, None], jnp.nan, logits)
+                bad = s.active & ~jnp.all(jnp.isfinite(logits), axis=-1)
+                key, sub = jax.random.split(self._key_of(s))
+                pm = (sampling_lib.history_mask(s.out, s.widx, V)
+                      if rp_on else None)
+                nxt = sampling_lib.sample(logits, sub, self.sampling,
+                                          penalty_mask=pm)
+            with jax.named_scope("bookkeeping"):
+                new_done = s.done + ntok
+                # a slot emits a token when its step reached the last
+                # prompt position (prefill -> first token) or is decoding
+                produced = s.active & (new_done >= s.plen) & ~bad
+                col = jnp.where(produced, s.widx, self.out_cap)
+                out = s.out.at[rows, col].set(nxt, mode="drop")
+                adv = produced.astype(jnp.int32)
+                tok = jnp.where(produced[:, None], nxt[:, None], s.tok)
+                return PagedState(
+                    tok=tok, prompt=s.prompt, plen=s.plen, done=new_done,
+                    remaining=s.remaining - adv,
+                    active=(s.active & ((s.remaining > 1) | ~produced)
+                            & ~bad),
+                    widx=s.widx + adv, out=out, task=s.task,
+                    key=self._wrap_key(key), caches=caches,
+                    dcaches=s.dcaches, steps=s.steps + 1,
+                    drafted=s.drafted, accepted=s.accepted,
+                    failed=s.failed | bad,
+                    first_step=stamp_first(s, produced))
 
         def spec_body(s):
             is_pf = s.done < s.plen
@@ -1285,9 +1313,10 @@ class Engine:
             mask_j = base_mask
             for j in range(K + 1):
                 dpos = jnp.where(is_pf, oob, s.done + j)
-                lg, dc = transformer.paged_step(
-                    dbase, self.cfg, self.rt.spec, dbc, dpl, tok_j, dc,
-                    tables, dpos, zero, task=task, policy=self.policy)
+                with jax.named_scope("model"):
+                    lg, dc = transformer.paged_step(
+                        dbase, self.cfg, self.rt.spec, dbc, dpl, tok_j, dc,
+                        tables, dpos, zero, task=task, policy=self.policy)
                 if j == K:
                     break
                 d_j, q_j = self._propose(lg, mask_j, keys[1 + j])
@@ -1308,9 +1337,10 @@ class Engine:
             def sync(dcc):
                 toks0 = jnp.where(is_pf[:, None], chunk, dec_pad)
                 spos = jnp.where(is_pf, s.done, oob)
-                _, dcc = transformer.paged_step(
-                    dbase, self.cfg, self.rt.spec, dbc, dpl, toks0, dcc,
-                    tables, spos, zero, task=task, policy=self.policy)
+                with jax.named_scope("model"):
+                    _, dcc = transformer.paged_step(
+                        dbase, self.cfg, self.rt.spec, dbc, dpl, toks0, dcc,
+                        tables, spos, zero, task=task, policy=self.policy)
                 return dcc
 
             dc = jax.lax.cond(jnp.any(is_pf), sync, lambda dcc: dcc, dc)
@@ -1319,10 +1349,11 @@ class Engine:
             dv = jnp.pad(jnp.concatenate([s.tok, d], axis=1),
                          ((0, 0), (0, C - (K + 1))))
             toks_v = jnp.where(is_pf[:, None], chunk, dv)
-            L, caches = transformer.paged_step(
-                base, self.cfg, self.rt.spec, bc, pl, toks_v, s.caches,
-                tables, s.done, zero, task=task, policy=self.policy,
-                all_logits=True)
+            with jax.named_scope("model"):
+                L, caches = transformer.paged_step(
+                    base, self.cfg, self.rt.spec, bc, pl, toks_v, s.caches,
+                    tables, s.done, zero, task=task, policy=self.policy,
+                    all_logits=True)
             # NaN guard (DESIGN.md §13): poison chaos-injected rows,
             # then fail any row whose relevant logit columns are
             # non-finite — the verifier block for decoding rows, the
@@ -1370,7 +1401,7 @@ class Engine:
                 key=self._wrap_key(keys[0]), caches=caches, dcaches=dc,
                 steps=s.steps + 1, drafted=s.drafted + K * nact,
                 accepted=s.accepted + jnp.sum(jnp.where(dec_act, n, 0)),
-                failed=s.failed | bad)
+                failed=s.failed | bad, first_step=stamp_first(s, m > 0))
 
         return jax.lax.while_loop(
             cond, spec_body if self._spec_on else body, state)
@@ -1431,7 +1462,8 @@ class Engine:
             task=z((b,)), key=self._fleet_key(key), caches=caches,
             dcaches=dcaches, steps=self._zero_ctr(),
             drafted=self._zero_ctr(), accepted=self._zero_ctr(),
-            failed=jnp.zeros((b,), bool))
+            failed=jnp.zeros((b,), bool),
+            first_step=jnp.full((b,), -1, jnp.int32))
 
     def init_paged_state(self, key) -> PagedState:
         """Fresh per-slot state over the engine's PERSISTENT block pools
@@ -1537,6 +1569,8 @@ class Engine:
             for idx, req in enumerate(requests)}
         self._req_status = {}
         self._req_preempts = {}
+        # idx -> {"t_admit", "t_first", "t_done"} as each is reached
+        self._req_times = {idx: {} for idx in range(len(requests))}
         try:
             if self.sv.cache_mode == "dense":
                 results = self._generate_dense(requests, key)
@@ -1553,7 +1587,8 @@ class Engine:
         self.last_results = [
             RequestResult(tokens=r, status=self._req_status.get(i, FINISHED),
                           n_generated=len(r),
-                          preemptions=self._req_preempts.get(i, 0))
+                          preemptions=self._req_preempts.get(i, 0),
+                          t_submit=t0, **self._req_times[i])
             for i, r in enumerate(results)]
         return results
 
@@ -1623,6 +1658,7 @@ class Engine:
                     continue
                 results[idx] = np.zeros((0,), np.int32)
                 self._req_status[idx] = stt
+                self._req_times[idx]["t_done"] = time.perf_counter()
                 if stt is CANCELLED:
                     st.cancelled += 1
                 else:
@@ -1641,6 +1677,7 @@ class Engine:
                 w = int(np.asarray(state.widx)[slot])
                 results[idx] = out[slot, :w].copy()
                 self._req_status[idx] = stt
+                self._req_times[idx]["t_done"] = time.perf_counter()
                 if stt is CANCELLED:
                     st.cancelled += 1
                 else:
@@ -1682,7 +1719,11 @@ class Engine:
                             st.adapter_hits += 1
                         task_ref = acq.slot
                     pending.popleft()
+                    times = self._req_times[idx]
+                    times["t_admit"] = time.perf_counter()
                     state = self._admit_request(state, slot, req, task_ref)
+                    # dense admission samples the request's first token
+                    times["t_first"] = time.perf_counter()
                     meta[slot] = idx
                     nan_at[slot] = (chaos.nan_for(self._rids[idx])
                                     if chaos is not None else -1)
@@ -1700,6 +1741,7 @@ class Engine:
                 if meta[slot] is not None and not active[slot]:
                     idx = meta[slot]
                     results[idx] = out[slot, : int(widx[slot])].copy()
+                    self._req_times[idx]["t_done"] = time.perf_counter()
                     if failedv[slot]:
                         # in-graph NaN guard tripped: fail the request
                         # with whatever it emitted before the fault
@@ -1711,17 +1753,17 @@ class Engine:
                     meta[slot] = None
                     nan_at[slot] = -1
                     st.evicted += 1
-        self._read_spec_stats(state, st)
+        self._read_loop_counters(state, st)
         return results  # type: ignore[return-value]
 
-    def _read_spec_stats(self, state, st) -> None:
-        """Fold the loop-carried speculation counters into EngineStats.
-        With |data| > 1 the counters are per-replica rows: the lockstep
-        global loop predicate makes steps identical across replicas
-        (max == any row), while drafted/accepted count each replica's
-        own rows and sum."""
+    def _read_loop_counters(self, state, st) -> None:
+        """Fold the loop-carried counters (steps, and the speculation
+        counters) into EngineStats. With |data| > 1 the counters are
+        per-replica rows: the lockstep global loop predicate makes steps
+        identical across replicas (max == any row), while
+        drafted/accepted count each replica's own rows and sum."""
         st.spec_k = self.spec.spec_k
-        st.spec_steps = int(np.asarray(state.steps).max())
+        st.decode_steps = int(np.asarray(state.steps).max())
         st.draft_tokens = int(np.asarray(state.drafted).sum())
         st.accepted_tokens = int(np.asarray(state.accepted).sum())
 
@@ -1779,7 +1821,7 @@ class Engine:
             self._pf_caches = pf_state.caches
             if self._spec_on:
                 self._pf_draft_pools = pf_state.dcaches
-        self._read_spec_stats(state, st)
+        self._read_loop_counters(state, st)
         return results  # type: ignore[return-value]
 
     def _paged_loop(self, state, pf_state, pendings, rcost, results, st):
@@ -1810,7 +1852,6 @@ class Engine:
         pf_stat = (dict(replica=-1, admitted=0, evicted=0, queue_depth=0,
                         backpressure_waits=0, kv_blocks_peak=0,
                         handoffs=0) if self._disagg else None)
-        ttft, tpot = [], []
         # idx -> tokens harvested before a preemption / failover kill;
         # the recompute re-admission carries them in the grown prompt
         # and ``finish`` prepends them to the final output
@@ -1847,6 +1888,7 @@ class Engine:
             results[idx] = arr
             if status is not None:
                 self._req_status[idx] = status
+            self._req_times[idx]["t_done"] = time.perf_counter()
             rr, cost = rcost[idx]
             self.router.complete(rr, cost)
 
@@ -2078,369 +2120,429 @@ class Engine:
         try:
             state, pf_state = self._paged_loop_iterations(
                 state, pf_state, pendings, rcost, results, st, meta,
-                pf_meta, handoffs, rstat, pf_stat, ttft, tpot, prior,
-                blocked, seq_ctr, note_peaks, finish, sweep, preempt_one,
-                drain_replica, hstep)
+                pf_meta, handoffs, rstat, pf_stat, prior, blocked, seq_ctr,
+                note_peaks, finish, sweep, preempt_one, drain_replica, hstep)
         finally:
             self._live = None
             for sc in self.scheds + self._pf_scheds:
                 sc.fault_hook = None
         for r in range(R):
             rstat[r]["queue_depth"] = len(pendings[r])
-        if ttft:
-            st.ttft_s = sum(ttft) / len(ttft)
-        if tpot:
-            st.tpot_s = sum(tpot) / len(tpot)
         st.replica_stats = rstat + ([pf_stat] if pf_stat else [])
         return state, pf_state
 
     def _paged_loop_iterations(self, state, pf_state, pendings, rcost,
                                results, st, meta, pf_meta, handoffs,
-                               rstat, pf_stat, ttft, tpot, prior, blocked,
-                               seq_ctr, note_peaks, finish, sweep,
-                               preempt_one, drain_replica, hstep):
+                               rstat, pf_stat, prior, blocked, seq_ctr,
+                               note_peaks, finish, sweep, preempt_one,
+                               drain_replica, hstep):
         """The iteration body of ``_paged_loop`` (split out so the
         closure scaffolding above stays readable). One iteration =
         chaos events -> lifecycle sweep -> admission (+ preemption) ->
-        handoff -> one jitted step per worker -> harvests -> audit."""
+        handoff -> one jitted step per worker -> wait -> harvests ->
+        audit, each phase under its ``repro.tracing`` span.
+
+        Each decode call is recorded on ``st.decode_call_log`` as
+        (t_dispatch, t_return, steps_before, steps_after), and a
+        request's first token is placed inside the call that produced it
+        by the loop step that emitted it (``PagedState.first_step``)."""
         R, B = self._dp, self.max_batch
         chaos = self._chaos
+        times = self._req_times
+        # each worker's loop-step count as last read back: the "before"
+        # of its next decode call
+        steps_seen = {"pf": 0, "dec": 0}
+
+        def stamp_first(wstate, held, call):
+            """t_first of the requests ``held`` [(slot, idx)] whose first
+            token came out of the decode call ``call``."""
+            held = [(s, i) for s, i in held if "t_first" not in times[i]]
+            if not held:
+                return
+            if call is None:        # no call this turn: seen only now
+                for _, idx in held:
+                    times[idx]["t_first"] = time.perf_counter()
+                return
+            t_d, t_r, s0, s1 = call
+            first = np.asarray(wstate.first_step)
+            for slot, idx in held:
+                frac = (int(first[slot]) - s0) / max(s1 - s0, 1)
+                times[idx]["t_first"] = t_d + frac * (t_r - t_d)
 
         while (any(pendings) or any(handoffs)
                or any(m is not None for m in meta)
                or any(m is not None for m in pf_meta)):
-            progressed = False
-            faults0 = (chaos.alloc_faults + chaos.scatter_faults
-                       if chaos is not None else 0)
-            # ---- chaos events: scripted cancels and the replica kill
-            if chaos is not None:
-                ev = chaos.tick(hstep)
-                for rid in ev["cancels"]:
-                    self._cancel_ids.add(rid)
-                if ev["kill"] is not None:
-                    state, pf_state = drain_replica(int(ev["kill"]),
-                                                    state, pf_state)
-                    progressed = True
-            hstep += 1
-            # ---- request lifecycle: cancels / expired deadlines
-            state, pf_state, swept = sweep(state, pf_state)
-            progressed = progressed or swept
-            # ---- admission: pending -> prefill worker (disagg) or
-            # straight into this replica's decode slots. Strict FIFO per
-            # replica: a blocked head waits for evictions rather than
-            # being overtaken (and, with preempt_after set, eventually
-            # preempts the youngest running request).
-            for r in range(R):
-                if not self.router.is_up(r):
-                    continue
-                scheds = self._pf_scheds if self._disagg else self.scheds
-                head_blocked = False
-                admitted_any = False
-                for slot in range(r * B, (r + 1) * B):
-                    mrow = pf_meta if self._disagg else meta
-                    if mrow[slot] is not None or not pendings[r]:
-                        continue
-                    ent = pendings[r][0]
-                    prompt, plen = ent["prompt"], ent["plen"]
-                    ns = ent["task"] if self._kv_tasked else None
-                    # the prefill worker computes prompt KV only (its one
-                    # emission needs no extra page), so plan with 0 new
-                    # tokens there; decode-side pages come at handoff
-                    plan = scheds[r].plan(
-                        np.asarray(prompt).tolist(),
-                        0 if self._disagg else ent["max_new"],
-                        namespace=ns,
-                        task=ent["task"] if self._reg_on else None)
-                    if plan is None:    # backpressure: out of KV blocks
-                        #                 or of adapter slots
-                        (pf_stat if self._disagg
-                         else rstat[r])["backpressure_waits"] += 1
-                        head_blocked = True
-                        break
-                    if (self._reg_on and plan.adapter_fault
-                            and chaos is not None
-                            and chaos.fail_scatter()):
-                        # simulated adapter-scatter failure BEFORE any
-                        # device work: unwind the whole admission —
-                        # deref the planned blocks, roll the pin back
-                        # (the slot stays mapped-but-UNLOADED; the
-                        # retry faults again), uncount the admission
-                        for bid in plan.blocks:
-                            scheds[r].bm.deref(bid)
-                        self.registries[r].release(ent["task"])
-                        st.admitted -= 1
-                        (pf_stat if self._disagg
-                         else rstat[r])["backpressure_waits"] += 1
-                        st.backpressure_waits += 1
-                        head_blocked = True
-                        break
-                    pendings[r].popleft()
-                    progressed = True
-                    admitted_any = True
-                    # adapter paging (DESIGN.md §12): the device state
-                    # carries the POOL-SLOT index (replica-offset into
-                    # the dp-striped pool), never the task id; a cold
-                    # task's slice is scattered in first
-                    task_ref = ent["task"]
-                    if self._reg_on:
-                        if plan.adapter_fault:
-                            self._adapter_fault_in(r, plan.adapter_slot,
-                                                   ent["task"])
-                        task_ref = (r * self.reg_cfg.max_resident_tasks
-                                    + plan.adapter_slot)
-                    target = pf_state if self._disagg else state
-                    if plan.cow is not None:
-                        target = self._pcow(
-                            target, jnp.int32(plan.cow[0]),
-                            jnp.int32(plan.cow[1]), jnp.int32(r))
-                    tab = (self._pf_tables if self._disagg
-                           else self._tables)
-                    row = np.full((self._p_tab,), self._num_blocks,
-                                  np.int32)
-                    row[:len(plan.blocks)] = plan.blocks
-                    tab[slot] = row
-                    prow = np.zeros((self._lp,), np.int32)
-                    prow[:plen] = prompt
-                    target = self._padmit(
-                        target, jnp.int32(slot), jnp.asarray(prow),
-                        jnp.int32(plen), jnp.int32(plan.n_cached),
-                        jnp.int32(1 if self._disagg
-                                  else ent["max_new"]),
-                        jnp.int32(task_ref), jnp.int32(0), jnp.int32(0))
-                    seq_ctr[0] += 1
-                    rid = self._rids[ent["idx"]]
-                    nan_vec = self._pf_nan if self._disagg else self._nan_at
-                    nan_vec[slot] = (chaos.nan_for(rid)
-                                     if chaos is not None else -1)
-                    mrow[slot] = dict(idx=ent["idx"], req=ent["req"],
-                                      prompt=prompt,
-                                      plen=plen, blocks=plan.blocks,
-                                      ns=ns, task=ent["task"],
-                                      task_ref=task_ref,
-                                      max_new=ent["max_new"],
-                                      seq=seq_ctr[0],
-                                      t_admit=time.perf_counter(),
-                                      t_first=None)
-                    if self._disagg:
-                        pf_state = target
-                        pf_stat["admitted"] += 1
-                    else:
-                        state = target
-                        rstat[r]["admitted"] += 1
-                note_peaks(r)
-                # ---- recompute preemption (DESIGN.md §13): the FIFO
-                # head has been backpressured preempt_after consecutive
-                # iterations — free the youngest running request so
-                # mixed long/short workloads cannot livelock
-                if head_blocked and not admitted_any:
-                    blocked[r] += 1
-                else:
-                    blocked[r] = 0
-                N = self.sv.preempt_after
-                if (N and not self._disagg and blocked[r] >= N
-                        and pendings[r]):
-                    state, did = preempt_one(r, state)
-                    if did:
-                        blocked[r] = 0
+            with tracing.span("engine.iteration"):
+                progressed = False
+                faults0 = (chaos.alloc_faults + chaos.scatter_faults
+                           if chaos is not None else 0)
+                # ---- chaos events: scripted cancels and the replica kill
+                if chaos is not None:
+                    ev = chaos.tick(hstep)
+                    for rid in ev["cancels"]:
+                        self._cancel_ids.add(rid)
+                    if ev["kill"] is not None:
+                        state, pf_state = drain_replica(int(ev["kill"]),
+                                                        state, pf_state)
                         progressed = True
-            # ---- handoff: finished prefills -> decode slots ----
-            if self._disagg:
+                hstep += 1
+                # ---- request lifecycle: cancels / expired deadlines
+                with tracing.span("engine.sweep"):
+                    state, pf_state, swept = sweep(state, pf_state)
+                progressed = progressed or swept
+                # ---- admission: pending -> prefill worker (disagg) or
+                # straight into this replica's decode slots. Strict FIFO
+                # per replica: a blocked head waits for evictions rather
+                # than being overtaken (and, with preempt_after set,
+                # eventually preempts the youngest running request).
                 for r in range(R):
                     if not self.router.is_up(r):
                         continue
-                    while handoffs[r]:
-                        h = handoffs[r][0]
-                        slot = next(
-                            (s for s in range(r * B, (r + 1) * B)
-                             if meta[s] is None), None)
-                        total = -(-(h["plen"] + h["max_new"])
-                                  // self._page)
-                        if (slot is None
-                                or self.bms[r].free_blocks < total):
-                            rstat[r]["backpressure_waits"] += 1
-                            st.backpressure_waits += 1
-                            break       # retried after the next eviction
-                        handoffs[r].popleft()
-                        progressed = True
-                        npf = len(h["blocks"])
-                        pairs = self._pf_bms[r].migrate_to(
-                            self.bms[r], h["blocks"])
-                        assert pairs is not None    # free checked above
-                        dst = ([d for _, d in pairs]
-                               + [self.bms[r].alloc()
-                                  for _ in range(total - npf)])
-                        src_ids = np.full((self._p_tab,),
-                                          self._num_blocks, np.int32)
-                        dst_ids = src_ids.copy()
-                        src_ids[:npf] = h["blocks"]
-                        dst_ids[:npf] = dst[:npf]
-                        state = self._pmigrate(
-                            state, pf_state.caches,
-                            (pf_state.dcaches if self._spec_on
-                             else jnp.int32(0)),
-                            jnp.asarray(src_ids), jnp.asarray(dst_ids),
-                            jnp.int32(r))
-                        row = np.full((self._p_tab,), self._num_blocks,
-                                      np.int32)
-                        row[:total] = dst
-                        self._tables[slot] = row
-                        prow = np.zeros((self._lp,), np.int32)
-                        prow[:h["plen"]] = h["prompt"]
-                        # done0 == plen and w0 = 1: the slot decodes
-                        # immediately from the migrated prompt KV, with
-                        # the prefill-emitted t0 already in the output
-                        state = self._padmit(
-                            state, jnp.int32(slot), jnp.asarray(prow),
-                            jnp.int32(h["plen"]), jnp.int32(h["plen"]),
-                            jnp.int32(h["max_new"] - 1),
-                            jnp.int32(h["task_ref"]), jnp.int32(h["t0"]),
-                            jnp.int32(1))
-                        rstat[r]["admitted"] += 1
-                        pf_stat["handoffs"] += 1
-                        # the adapter pin taken at prefill admission rides
-                        # the handoff (pf + decode share the replica's
-                        # registry) and is released at decode harvest
-                        meta[slot] = dict(
-                            idx=h["idx"], req=h["req"],
-                            prompt=h["prompt"], plen=h["plen"],
-                            blocks=dst, ns=h["ns"], task=h["task"],
-                            task_ref=h["task_ref"],
-                            max_new=h["max_new"], seq=h["seq"],
-                            t_admit=h["t_admit"], t_first=h["t_first"])
-                        # the NaN-injection threshold follows the
-                        # request onto its decode slot
-                        self._nan_at[slot] = (
-                            chaos.nan_for(self._rids[h["idx"]])
-                            if chaos is not None else -1)
+                    scheds = (self._pf_scheds if self._disagg
+                              else self.scheds)
+                    head_blocked = False
+                    admitted_any = False
+                    for slot in range(r * B, (r + 1) * B):
+                        mrow = pf_meta if self._disagg else meta
+                        if mrow[slot] is not None or not pendings[r]:
+                            continue
+                        ent = pendings[r][0]
+                        with tracing.span("engine.admit",
+                                          rid=self._rids[ent["idx"]],
+                                          slot=slot, task=ent["task"]):
+                            prompt, plen = ent["prompt"], ent["plen"]
+                            ns = ent["task"] if self._kv_tasked else None
+                            # the prefill worker computes prompt KV only
+                            # (its one emission needs no extra page), so
+                            # plan with 0 new tokens there; decode-side
+                            # pages come at handoff
+                            plan = scheds[r].plan(
+                                np.asarray(prompt).tolist(),
+                                0 if self._disagg else ent["max_new"],
+                                namespace=ns,
+                                task=ent["task"] if self._reg_on else None)
+                            if plan is None:    # backpressure: out of KV
+                                #                 blocks or adapter slots
+                                (pf_stat if self._disagg
+                                 else rstat[r])["backpressure_waits"] += 1
+                                head_blocked = True
+                                break
+                            if (self._reg_on and plan.adapter_fault
+                                    and chaos is not None
+                                    and chaos.fail_scatter()):
+                                # simulated adapter-scatter failure BEFORE
+                                # any device work: unwind the whole
+                                # admission — deref the planned blocks,
+                                # roll the pin back (the slot stays
+                                # mapped-but-UNLOADED; the retry faults
+                                # again), uncount the admission
+                                for bid in plan.blocks:
+                                    scheds[r].bm.deref(bid)
+                                self.registries[r].release(ent["task"])
+                                st.admitted -= 1
+                                (pf_stat if self._disagg
+                                 else rstat[r])["backpressure_waits"] += 1
+                                st.backpressure_waits += 1
+                                head_blocked = True
+                                break
+                            pendings[r].popleft()
+                            progressed = True
+                            admitted_any = True
+                            # adapter paging (DESIGN.md §12): the device
+                            # state carries the POOL-SLOT index
+                            # (replica-offset into the dp-striped pool),
+                            # never the task id; a cold task's slice is
+                            # scattered in first
+                            task_ref = ent["task"]
+                            if self._reg_on:
+                                if plan.adapter_fault:
+                                    with tracing.span(
+                                            "engine.adapter_fault"):
+                                        self._adapter_fault_in(
+                                            r, plan.adapter_slot,
+                                            ent["task"])
+                                task_ref = (
+                                    r * self.reg_cfg.max_resident_tasks
+                                    + plan.adapter_slot)
+                            target = pf_state if self._disagg else state
+                            if plan.cow is not None:
+                                with tracing.span("engine.cow"):
+                                    target = self._pcow(
+                                        target, jnp.int32(plan.cow[0]),
+                                        jnp.int32(plan.cow[1]),
+                                        jnp.int32(r))
+                            tab = (self._pf_tables if self._disagg
+                                   else self._tables)
+                            row = np.full((self._p_tab,), self._num_blocks,
+                                          np.int32)
+                            row[:len(plan.blocks)] = plan.blocks
+                            tab[slot] = row
+                            prow = np.zeros((self._lp,), np.int32)
+                            prow[:plen] = prompt
+                            target = self._padmit(
+                                target, jnp.int32(slot), jnp.asarray(prow),
+                                jnp.int32(plen), jnp.int32(plan.n_cached),
+                                jnp.int32(1 if self._disagg
+                                          else ent["max_new"]),
+                                jnp.int32(task_ref), jnp.int32(0),
+                                jnp.int32(0))
+                            seq_ctr[0] += 1
+                            rid = self._rids[ent["idx"]]
+                            nan_vec = (self._pf_nan if self._disagg
+                                       else self._nan_at)
+                            nan_vec[slot] = (chaos.nan_for(rid)
+                                             if chaos is not None else -1)
+                            mrow[slot] = dict(idx=ent["idx"], req=ent["req"],
+                                              prompt=prompt,
+                                              plen=plen, blocks=plan.blocks,
+                                              ns=ns, task=ent["task"],
+                                              task_ref=task_ref,
+                                              max_new=ent["max_new"],
+                                              seq=seq_ctr[0])
+                            times[ent["idx"]].setdefault(
+                                "t_admit", time.perf_counter())
+                            if self._disagg:
+                                pf_state = target
+                                pf_stat["admitted"] += 1
+                            else:
+                                state = target
+                                rstat[r]["admitted"] += 1
                     note_peaks(r)
-            # ---- step the worker loops until some slot finishes ----
-            stepped = False
-            if (self._disagg
-                    and bool(np.any(np.asarray(pf_state.active)))):
-                pf_state = self._pdecode(*self._step_weights, pf_state,
-                                         jnp.asarray(self._pf_tables),
-                                         jnp.asarray(self._pf_nan))
-                st.decode_calls += 1
-                stepped = True
-            if bool(np.any(np.asarray(state.active))):
-                state = self._pdecode(*self._step_weights, state,
-                                      jnp.asarray(self._tables),
-                                      jnp.asarray(self._nan_at))
-                st.decode_calls += 1
-                stepped = True
-            # ---- harvest prefill completions -> handoff queue ----
-            if self._disagg:
-                pactive = np.asarray(pf_state.active)
-                pout = np.asarray(pf_state.out)
-                pfailed = np.asarray(pf_state.failed)
-                t = time.perf_counter()
-                for slot in range(self._slots):
-                    m = pf_meta[slot]
-                    if m is None or pactive[slot]:
-                        continue
-                    progressed = True
-                    r = slot // B
-                    t0 = int(pout[slot, 0])
-                    if pfailed[slot]:
-                        # in-graph NaN guard tripped during prefill: the
-                        # KV is suspect — fail the request, index nothing
-                        self._pf_scheds[r].release(
+                    # ---- recompute preemption (DESIGN.md §13): the FIFO
+                    # head has been backpressured preempt_after
+                    # consecutive iterations — free the youngest running
+                    # request so mixed long/short workloads cannot
+                    # livelock
+                    if head_blocked and not admitted_any:
+                        blocked[r] += 1
+                    else:
+                        blocked[r] = 0
+                    N = self.sv.preempt_after
+                    if (N and not self._disagg and blocked[r] >= N
+                            and pendings[r]):
+                        state, did = preempt_one(r, state)
+                        if did:
+                            blocked[r] = 0
+                            progressed = True
+                # ---- handoff: finished prefills -> decode slots ----
+                if self._disagg:
+                    for r in range(R):
+                        if not self.router.is_up(r):
+                            continue
+                        while handoffs[r]:
+                            h = handoffs[r][0]
+                            slot = next(
+                                (s for s in range(r * B, (r + 1) * B)
+                                 if meta[s] is None), None)
+                            total = -(-(h["plen"] + h["max_new"])
+                                      // self._page)
+                            if (slot is None
+                                    or self.bms[r].free_blocks < total):
+                                rstat[r]["backpressure_waits"] += 1
+                                st.backpressure_waits += 1
+                                break   # retried after the next eviction
+                            handoffs[r].popleft()
+                            progressed = True
+                            npf = len(h["blocks"])
+                            pairs = self._pf_bms[r].migrate_to(
+                                self.bms[r], h["blocks"])
+                            assert pairs is not None   # free checked above
+                            dst = ([d for _, d in pairs]
+                                   + [self.bms[r].alloc()
+                                      for _ in range(total - npf)])
+                            src_ids = np.full((self._p_tab,),
+                                              self._num_blocks, np.int32)
+                            dst_ids = src_ids.copy()
+                            src_ids[:npf] = h["blocks"]
+                            dst_ids[:npf] = dst[:npf]
+                            state = self._pmigrate(
+                                state, pf_state.caches,
+                                (pf_state.dcaches if self._spec_on
+                                 else jnp.int32(0)),
+                                jnp.asarray(src_ids), jnp.asarray(dst_ids),
+                                jnp.int32(r))
+                            row = np.full((self._p_tab,), self._num_blocks,
+                                          np.int32)
+                            row[:total] = dst
+                            self._tables[slot] = row
+                            prow = np.zeros((self._lp,), np.int32)
+                            prow[:h["plen"]] = h["prompt"]
+                            # done0 == plen and w0 = 1: the slot decodes
+                            # immediately from the migrated prompt KV,
+                            # with the prefill-emitted t0 already in the
+                            # output
+                            state = self._padmit(
+                                state, jnp.int32(slot), jnp.asarray(prow),
+                                jnp.int32(h["plen"]), jnp.int32(h["plen"]),
+                                jnp.int32(h["max_new"] - 1),
+                                jnp.int32(h["task_ref"]),
+                                jnp.int32(h["t0"]), jnp.int32(1))
+                            rstat[r]["admitted"] += 1
+                            pf_stat["handoffs"] += 1
+                            # the adapter pin taken at prefill admission
+                            # rides the handoff (pf + decode share the
+                            # replica's registry) and is released at
+                            # decode harvest
+                            meta[slot] = dict(
+                                idx=h["idx"], req=h["req"],
+                                prompt=h["prompt"], plen=h["plen"],
+                                blocks=dst, ns=h["ns"], task=h["task"],
+                                task_ref=h["task_ref"],
+                                max_new=h["max_new"], seq=h["seq"])
+                            # the NaN-injection threshold follows the
+                            # request onto its decode slot
+                            self._nan_at[slot] = (
+                                chaos.nan_for(self._rids[h["idx"]])
+                                if chaos is not None else -1)
+                        note_peaks(r)
+                # ---- step the worker loops until some slot finishes ----
+                calls = {}
+                with tracing.span("engine.dispatch"):
+                    if (self._disagg
+                            and bool(np.any(np.asarray(pf_state.active)))):
+                        calls["pf"] = time.perf_counter()
+                        pf_state = self._pdecode(
+                            *self._step_weights, pf_state,
+                            jnp.asarray(self._pf_tables),
+                            jnp.asarray(self._pf_nan))
+                        st.decode_calls += 1
+                    if bool(np.any(np.asarray(state.active))):
+                        calls["dec"] = time.perf_counter()
+                        state = self._pdecode(*self._step_weights, state,
+                                              jnp.asarray(self._tables),
+                                              jnp.asarray(self._nan_at))
+                        st.decode_calls += 1
+                stepped = bool(calls)
+                # ---- the host's one wait on the device: the loops'
+                # results, each call then closed with its step count (read
+                # in the same round trip as the active flags)
+                with tracing.span("engine.wait"):
+                    for w, ws in (("pf", pf_state), ("dec", state)):
+                        if w not in calls:
+                            continue
+                        _, steps, _ = jax.device_get(
+                            (ws.active, ws.steps, ws.first_step))
+                        t_ret = time.perf_counter()
+                        s1 = int(np.max(steps))
+                        calls[w] = (calls[w], t_ret, steps_seen[w], s1)
+                        st.decode_call_log.append(calls[w])
+                        steps_seen[w] = s1
+                with tracing.span("engine.harvest"):
+                    # ---- harvest prefill completions -> handoff queue --
+                    if self._disagg:
+                        pactive = np.asarray(pf_state.active)
+                        pout = np.asarray(pf_state.out)
+                        pfailed = np.asarray(pf_state.failed)
+                        stamp_first(
+                            pf_state,
+                            [(s, m["idx"]) for s, m in enumerate(pf_meta)
+                             if m is not None and not pactive[s]
+                             and not pfailed[s]], calls.get("pf"))
+                        for slot in range(self._slots):
+                            m = pf_meta[slot]
+                            if m is None or pactive[slot]:
+                                continue
+                            progressed = True
+                            r = slot // B
+                            t0 = int(pout[slot, 0])
+                            if pfailed[slot]:
+                                # in-graph NaN guard tripped during
+                                # prefill: the KV is suspect — fail the
+                                # request, index nothing
+                                self._pf_scheds[r].release(
+                                    m["prompt"], m["blocks"],
+                                    namespace=m["ns"], register=False,
+                                    task=(m["task"] if self._reg_on
+                                          else None))
+                                self._pf_tables[slot] = self._num_blocks
+                                self._pf_nan[slot] = -1
+                                pf_meta[slot] = None
+                                pf_stat["evicted"] += 1
+                                st.failed_requests += 1
+                                st.numerics_faults += 1
+                                finish(m["idx"], [], FAILED)
+                                continue
+                            # prompt KV is complete: index it for prefix
+                            # reuse BEFORE the handoff derefs the slot's
+                            # refs, so the cached entries stay pinned in
+                            # the prefill pool
+                            if self._pf_prefixes[r] is not None:
+                                self._pf_prefixes[r].register(
+                                    m["prompt"], m["blocks"],
+                                    namespace=m["ns"])
+                            self._pf_tables[slot] = self._num_blocks
+                            self._pf_nan[slot] = -1
+                            pf_meta[slot] = None
+                            pf_stat["evicted"] += 1
+                            if m["max_new"] == 1:
+                                # the prefill emission IS the whole output
+                                self._pf_scheds[r].release(
+                                    m["prompt"], m["blocks"],
+                                    namespace=m["ns"], register=False,
+                                    task=(m["task"] if self._reg_on
+                                          else None))
+                                finish(m["idx"], [t0])
+                                continue
+                            handoffs[r].append(dict(
+                                idx=m["idx"], req=m["req"],
+                                prompt=m["prompt"], plen=m["plen"],
+                                blocks=m["blocks"], ns=m["ns"],
+                                task=m["task"], task_ref=m["task_ref"],
+                                max_new=m["max_new"], seq=m["seq"], t0=t0))
+                    # ---- harvest decode completions ----
+                    active = np.asarray(state.active)
+                    out = np.asarray(state.out)
+                    widx = np.asarray(state.widx)
+                    failedv = np.asarray(state.failed)
+                    stamp_first(state,
+                                [(s, m["idx"]) for s, m in enumerate(meta)
+                                 if m is not None and widx[s] > 0],
+                                calls.get("dec"))
+                    for slot in range(self._slots):
+                        m = meta[slot]
+                        if m is None or active[slot]:
+                            continue
+                        progressed = True
+                        r = slot // B
+                        ntok = int(widx[slot])
+                        bad = bool(failedv[slot])
+                        # prompt pages are fully computed now: index them
+                        # for prefix reuse (unless the prefill pool's
+                        # cache already did, or the NaN guard fired —
+                        # suspect KV is never indexed), return the rest
+                        # to the free list
+                        self.scheds[r].release(
                             m["prompt"], m["blocks"], namespace=m["ns"],
-                            register=False,
+                            register=not (self._disagg or bad),
                             task=m["task"] if self._reg_on else None)
-                        self._pf_tables[slot] = self._num_blocks
-                        self._pf_nan[slot] = -1
-                        pf_meta[slot] = None
-                        pf_stat["evicted"] += 1
-                        st.failed_requests += 1
-                        st.numerics_faults += 1
-                        finish(m["idx"], [], FAILED)
+                        self._tables[slot] = self._num_blocks
+                        self._nan_at[slot] = -1
+                        rstat[r]["evicted"] += 1
+                        if bad:
+                            st.failed_requests += 1
+                            st.numerics_faults += 1
+                            finish(m["idx"], out[slot, :ntok], FAILED)
+                        else:
+                            finish(m["idx"], out[slot, :ntok])
+                        meta[slot] = None
+                if not (progressed or stepped):
+                    faults1 = (chaos.alloc_faults + chaos.scatter_faults
+                               if chaos is not None else 0)
+                    if faults1 > faults0:
+                        # the stall was manufactured (injected alloc /
+                        # scatter faults blocked every admission this
+                        # iteration) — retry, this is not a real deadlock
                         continue
-                    # prompt KV is complete: index it for prefix reuse
-                    # BEFORE the handoff derefs the slot's refs, so the
-                    # cached entries stay pinned in the prefill pool
-                    if self._pf_prefixes[r] is not None:
-                        self._pf_prefixes[r].register(
-                            m["prompt"], m["blocks"], namespace=m["ns"])
-                    self._pf_tables[slot] = self._num_blocks
-                    self._pf_nan[slot] = -1
-                    pf_meta[slot] = None
-                    pf_stat["evicted"] += 1
-                    ttft.append(t - m["t_admit"])
-                    if m["max_new"] == 1:
-                        # the prefill emission IS the whole output
-                        self._pf_scheds[r].release(
-                            m["prompt"], m["blocks"], namespace=m["ns"],
-                            register=False,
-                            task=m["task"] if self._reg_on else None)
-                        finish(m["idx"], [t0])
-                        continue
-                    handoffs[r].append(dict(
-                        idx=m["idx"], req=m["req"], prompt=m["prompt"],
-                        plen=m["plen"], blocks=m["blocks"], ns=m["ns"],
-                        task=m["task"], task_ref=m["task_ref"],
-                        max_new=m["max_new"], seq=m["seq"],
-                        t0=t0, t_admit=m["t_admit"], t_first=t))
-            # ---- harvest decode completions ----
-            active = np.asarray(state.active)
-            out = np.asarray(state.out)
-            widx = np.asarray(state.widx)
-            failedv = np.asarray(state.failed)
-            t = time.perf_counter()
-            for slot in range(self._slots):
-                m = meta[slot]
-                if m is None:
-                    continue
-                if m["t_first"] is None and widx[slot] > 0:
-                    m["t_first"] = t
-                    ttft.append(t - m["t_admit"])
-                if active[slot]:
-                    continue
-                progressed = True
-                r = slot // B
-                ntok = int(widx[slot])
-                bad = bool(failedv[slot])
-                # prompt pages are fully computed now: index them for
-                # prefix reuse (unless the prefill pool's cache already
-                # did, or the NaN guard fired — suspect KV is never
-                # indexed), return the rest to the free list
-                self.scheds[r].release(m["prompt"], m["blocks"],
-                                       namespace=m["ns"],
-                                       register=not (self._disagg or bad),
-                                       task=(m["task"] if self._reg_on
-                                             else None))
-                self._tables[slot] = self._num_blocks
-                self._nan_at[slot] = -1
-                rstat[r]["evicted"] += 1
-                # phase split is resolvable only when the first token was
-                # observed at an earlier loop exit than the completion
-                if (m["t_first"] is not None and ntok > 1
-                        and m["t_first"] < t):
-                    tpot.append((t - m["t_first"]) / (ntok - 1))
-                if bad:
-                    st.failed_requests += 1
-                    st.numerics_faults += 1
-                    finish(m["idx"], out[slot, :ntok], FAILED)
-                else:
-                    finish(m["idx"], out[slot, :ntok])
-                meta[slot] = None
-            if not (progressed or stepped):
-                faults1 = (chaos.alloc_faults + chaos.scatter_faults
-                           if chaos is not None else 0)
-                if faults1 > faults0:
-                    # the stall was manufactured (injected alloc /
-                    # scatter faults blocked every admission this
-                    # iteration) — retry, this is not a real deadlock
-                    continue
-                # nothing decoded, admitted, handed off or harvested:
-                # the queued work can never fit (classic case: a request
-                # needing more KV blocks than the pool can ever free)
-                raise RuntimeError(
-                    "paged admission deadlock: request needs more KV "
-                    "blocks (or adapter slots) than the pool can ever "
-                    "free")
-            if chaos is not None and chaos.audit_every_step:
-                chaos_mod.audit(self)
+                    # nothing decoded, admitted, handed off or harvested:
+                    # the queued work can never fit (classic case: a
+                    # request needing more KV blocks than the pool can
+                    # ever free)
+                    raise RuntimeError(
+                        "paged admission deadlock: request needs more KV "
+                        "blocks (or adapter slots) than the pool can ever "
+                        "free")
+                if chaos is not None and chaos.audit_every_step:
+                    chaos_mod.audit(self)
         return state, pf_state
 
 

@@ -3,7 +3,9 @@
 The engine fills one ``EngineStats`` per ``generate`` call and keeps it on
 ``engine.last_stats``; ``benchmarks/bench_serving.py`` and
 ``examples/serve.py`` print it. Everything here is host-side counting —
-no device syncs beyond what the engine already does.
+no device syncs beyond what the engine already does. Per-request times
+live on ``engine.last_results`` (``RequestResult.t_*``);
+``latency_quantiles`` reduces them.
 
 Byte accounting is GLOBAL (all shards): ``block_bytes`` / ``kv_bytes_peak``
 describe the whole logical cache regardless of the serve mesh, so
@@ -15,6 +17,8 @@ global figures down to what one device actually holds (DESIGN.md §9).
 from __future__ import annotations
 
 import dataclasses
+
+import numpy as np
 
 
 @dataclasses.dataclass
@@ -28,6 +32,11 @@ class EngineStats:
     admitted: int = 0
     evicted: int = 0
     decode_calls: int = 0        # host->device decode-loop invocations
+    decode_steps: int = 0        # decode-loop iterations (engine steps)
+    decode_call_log: list = dataclasses.field(default_factory=list)
+    #   paged mode, one tuple per decode call: (t_dispatch, t_return,
+    #   steps_before, steps_after) — perf_counter seconds around the call
+    #   and its wait, and the worker's loop-step count before and after
     decode_traces: int = 0       # jit (re)traces of the decode graph
     prefill_traces: int = 0      # dense mode: per-bucket prefill compiles
     # --- quantization (DESIGN.md §8) ---
@@ -51,10 +60,6 @@ class EngineStats:
     #   "kv_blocks_peak"}. Populated for every paged generate (one entry
     #   on a mesh of 1); under disaggregation the prefill worker reports
     #   as replica -1 with an extra "handoffs" count.
-    # --- latency phase split (host-measured, wall-clock) ---
-    ttft_s: float = 0.0          # mean time-to-first-token over requests
-    tpot_s: float = 0.0          # mean per-token decode latency after the
-    #                              first token (time-per-output-token)
     # --- prefix cache ---
     prefix_lookups: int = 0      # admissions that consulted the cache
     prefix_hit_tokens: int = 0   # prompt tokens served from cached blocks
@@ -75,7 +80,6 @@ class EngineStats:
     #                              (also counted in backpressure_waits)
     # --- speculative decode (DESIGN.md §10) ---
     spec_k: int = 0              # drafts per engine step (0 = spec off)
-    spec_steps: int = 0          # decode-loop iterations (engine steps)
     draft_tokens: int = 0        # drafter proposals (active decode rows)
     accepted_tokens: int = 0     # proposals the verifier accepted
     # --- resilience (request lifecycle / failover, DESIGN.md §13) ---
@@ -139,16 +143,18 @@ class EngineStats:
 
     @property
     def tokens_per_step(self) -> float:
-        """Committed tokens per decode-loop iteration — speculation's
-        whole point is pushing this above 1.0 (chunked prefill steps
-        count too, so long prompts dilute it slightly)."""
-        if not self.spec_steps:
+        """Committed tokens per decode-loop iteration: how full the
+        batch runs, and with speculation above 1.0 per slot (chunked
+        prefill steps count too, so long prompts dilute it)."""
+        if not self.decode_steps:
             return 0.0
-        return self.tokens_generated / self.spec_steps
+        return self.tokens_generated / self.decode_steps
 
-    def summary(self) -> str:
+    def summary(self, results=()) -> str:
         """One-line human-readable digest (printed by examples/serve.py
-        and bench_serving)."""
+        and bench_serving). ``results`` (``engine.last_results``) adds
+        the p50/p95 time to first token and per output token."""
+        lat = latency_quantiles(results)
         return (f"mode={self.cache_mode} w={self.weights_dtype} "
                 f"kv={self.kv_dtype} shards={self.shards} "
                 + (f"dp={self.data_shards} " if self.data_shards > 1
@@ -156,9 +162,8 @@ class EngineStats:
                 + f"reqs={self.requests} "
                 f"toks={self.tokens_generated} "
                 f"tok/s={self.tokens_per_s:.1f} "
-                + (f"ttft={self.ttft_s * 1e3:.1f}ms "
-                   f"tpot={self.tpot_s * 1e3:.2f}ms "
-                   if self.ttft_s else "")
+                f"tok/step={self.tokens_per_step:.2f} "
+                + "".join(f"{k}={v * 1e3:.1f}ms " for k, v in lat.items())
                 + f"kv_blocks_peak={self.kv_blocks_peak}/{self.num_blocks} "
                 f"kv_bytes_peak={self.kv_bytes_peak} "
                 f"(per_shard={self.kv_bytes_peak_per_shard}) "
@@ -174,8 +179,7 @@ class EngineStats:
                    f"awaits={self.adapter_waits}"
                    if self.max_resident_tasks else "")
                 + (f" spec_k={self.spec_k} "
-                   f"accept={self.acceptance_rate:.2f} "
-                   f"tok/step={self.tokens_per_step:.2f}"
+                   f"accept={self.acceptance_rate:.2f}"
                    if self.spec_k else "")
                 + (f" cancelled={self.cancelled} "
                    f"timeouts={self.timeouts} "
@@ -187,3 +191,21 @@ class EngineStats:
                    if (self.cancelled or self.timeouts or self.preemptions
                        or self.failed_requests or self.numerics_faults
                        or self.replicas_lost) else ""))
+
+
+def latency_quantiles(results) -> dict:
+    """p50 and p95 seconds of time to first token (from submission) and
+    of time per output token after the first, over ``RequestResult``s
+    (``engine.last_results``); a quantity no request reached is left
+    out."""
+    ttft = [r.t_first - r.t_submit for r in results
+            if r.t_first is not None and r.t_submit is not None]
+    tpot = [(r.t_done - r.t_first) / (r.n_generated - 1) for r in results
+            if r.t_first is not None and r.t_done is not None
+            and r.n_generated > 1]
+    out = {}
+    for name, xs in (("ttft", ttft), ("tpot", tpot)):
+        if xs:
+            p50, p95 = np.percentile(xs, [50, 95])
+            out[f"{name}_p50"], out[f"{name}_p95"] = float(p50), float(p95)
+    return out

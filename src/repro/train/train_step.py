@@ -81,36 +81,42 @@ def make_train_step(cfg: ModelConfig, spec: peft_api.AdapterSpec,
     policy = kernel_dispatch.resolve(kernels)
 
     def loss(adapter, base, frozen, batch):
-        return model_lib.loss_fn(adapter, base, frozen, batch, cfg, spec,
-                                 remat=remat, chunk=chunk, policy=policy)
+        with jax.named_scope("forward"):
+            return model_lib.loss_fn(adapter, base, frozen, batch, cfg,
+                                     spec, remat=remat, chunk=chunk,
+                                     policy=policy)
 
     grad_fn = jax.value_and_grad(loss, has_aux=True)
 
     def step_fn(state: TrainState, base, frozen, batch):
+        # named scopes (repro.tracing) label each phase's device ops
         nmb = train_cfg.microbatch
-        if nmb and nmb > 1:
-            def micro(acc, mb):
-                (l, m), g = grad_fn(state.adapter, base, frozen, mb)
-                acc_g, acc_l = acc
-                return (jax.tree_util.tree_map(jnp.add, acc_g, g),
-                        acc_l + l), m
-            zero = (jax.tree_util.tree_map(
-                lambda a: jnp.zeros_like(a, jnp.float32), state.adapter),
-                jnp.zeros((), jnp.float32))
-            mbs = jax.tree_util.tree_map(
-                lambda a: a.reshape((nmb, a.shape[0] // nmb) + a.shape[1:]),
-                batch)
-            (grads, lsum), ms = jax.lax.scan(micro, zero, mbs)
-            grads = jax.tree_util.tree_map(lambda g: g / nmb, grads)
-            loss_val = lsum / nmb
-            metrics = {k: jnp.mean(v) for k, v in ms.items()}
-        else:
-            (loss_val, metrics), grads = grad_fn(state.adapter, base, frozen,
-                                                 batch)
-        grads, residual = compressor(grads, state.residual)
-        lr = schedule(state.opt.step)
-        new_adapter, new_opt, gnorm = adamw.update(
-            grads, state.opt, state.adapter, opt_cfg, lr)
+        with jax.named_scope("gradient"):
+            if nmb and nmb > 1:
+                def micro(acc, mb):
+                    (l, m), g = grad_fn(state.adapter, base, frozen, mb)
+                    acc_g, acc_l = acc
+                    return (jax.tree_util.tree_map(jnp.add, acc_g, g),
+                            acc_l + l), m
+                zero = (jax.tree_util.tree_map(
+                    lambda a: jnp.zeros_like(a, jnp.float32),
+                    state.adapter), jnp.zeros((), jnp.float32))
+                mbs = jax.tree_util.tree_map(
+                    lambda a: a.reshape((nmb, a.shape[0] // nmb)
+                                        + a.shape[1:]), batch)
+                (grads, lsum), ms = jax.lax.scan(micro, zero, mbs)
+                grads = jax.tree_util.tree_map(lambda g: g / nmb, grads)
+                loss_val = lsum / nmb
+                metrics = {k: jnp.mean(v) for k, v in ms.items()}
+            else:
+                (loss_val, metrics), grads = grad_fn(state.adapter, base,
+                                                     frozen, batch)
+        with jax.named_scope("compression"):
+            grads, residual = compressor(grads, state.residual)
+        with jax.named_scope("optimizer"):
+            lr = schedule(state.opt.step)
+            new_adapter, new_opt, gnorm = adamw.update(
+                grads, state.opt, state.adapter, opt_cfg, lr)
         new_state = TrainState(adapter=new_adapter, opt=new_opt,
                                residual=residual, step=state.step + 1)
         metrics = dict(metrics, loss=loss_val, grad_norm=gnorm, lr=lr)

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.checkpoint import CheckpointManager
 from repro.config.base import RunConfig
 from repro.core import dmrg as dmrg_lib
@@ -99,7 +100,8 @@ class Trainer:
                 "applied_epochs": list(self._dmrg_applied),
                 "ranks": [int(r) for r in tt.ranks(adapter["cores"])],
             }
-        self.ckpt.save(step, self.state, meta)
+        with tracing.span("trainer.checkpoint", step=step):
+            self.ckpt.save(step, self.state, meta)
 
     # ------------------------------------------------------------------
     def _maybe_dmrg(self, step: int) -> None:
@@ -115,16 +117,19 @@ class Trainer:
             return
         warm = self.run.train.dmrg_warm_moments
         moments = (self.state.opt.mu, self.state.opt.nu) if warm else None
-        res = dmrg_lib.dmrg_sweep(self.state.adapter, target_rank=target,
-                                  moments=moments)
-        n_before = peft_api.count_trainable(self.spec, self.state.adapter)
-        n_after = peft_api.count_trainable(self.spec, res.params)
-        self.state = ts.reinit_after_dmrg(self.state, res.params,
-                                          self.compressor,
-                                          moments=res.moments)
-        # the host-side resplit left stale placements: put the rank-changed
-        # cores + moments back onto the ambient mesh before the retrace
-        self.state = rules.reshard_after_reshape(self.state)
+        with tracing.span("trainer.dmrg", step=step):
+            res = dmrg_lib.dmrg_sweep(self.state.adapter,
+                                      target_rank=target, moments=moments)
+            n_before = peft_api.count_trainable(self.spec,
+                                                self.state.adapter)
+            n_after = peft_api.count_trainable(self.spec, res.params)
+            self.state = ts.reinit_after_dmrg(self.state, res.params,
+                                              self.compressor,
+                                              moments=res.moments)
+            # the host-side resplit left stale placements: put the
+            # rank-changed cores + moments back onto the ambient mesh
+            # before the retrace
+            self.state = rules.reshard_after_reshape(self.state)
         self._dmrg_applied.append(epoch)
         print(f"[trainer] DMRG sweep @step {step}: ranks -> {res.ranks} "
               f"params {n_before} -> {n_after} "
@@ -144,30 +149,39 @@ class Trainer:
         steps = steps or self.total_steps
         start = int(self.state.step)
         for step in range(start, steps):
-            if self.failure_injector is not None:
-                self.failure_injector.check(step)
-            batch = self._next_batch(step)
-            t0 = time.perf_counter()
-            self.state, metrics = self.step_fn(self.state, self.base,
-                                               self.frozen, batch)
-            metrics = {k: float(v) for k, v in metrics.items()}
-            dt = time.perf_counter() - t0
-            self.watchdog.step(step, dt)
-            metrics["step_time_s"] = dt
-            self.history.append((step, metrics))
-            if self.on_metrics is not None:
-                self.on_metrics(step, metrics)
-            # sweep BEFORE the boundary checkpoint: a save at an epoch edge
-            # must capture the post-sweep triple, or a resume from it would
-            # silently lose the rank change
-            self._maybe_dmrg(step + 1)
-            if self.run.train.ckpt_every and \
-                    (step + 1) % self.run.train.ckpt_every == 0:
-                self._save(step + 1)
+            with tracing.step_span("trainer.step", step):
+                self._step(step)
         if self.ckpt is not None:
             self._save(steps)
             self.ckpt.wait()
         return self.history
+
+    def _step(self, step: int) -> None:
+        """One training step, each phase under its ``repro.tracing``
+        span."""
+        if self.failure_injector is not None:
+            self.failure_injector.check(step)
+        with tracing.span("trainer.batch"):
+            batch = self._next_batch(step)
+        t0 = time.perf_counter()
+        with tracing.span("trainer.dispatch"):
+            self.state, metrics = self.step_fn(self.state, self.base,
+                                               self.frozen, batch)
+        with tracing.span("trainer.wait"):
+            metrics = {k: float(v) for k, v in metrics.items()}
+        dt = time.perf_counter() - t0
+        self.watchdog.step(step, dt)
+        metrics["step_time_s"] = dt
+        self.history.append((step, metrics))
+        if self.on_metrics is not None:
+            self.on_metrics(step, metrics)
+        # sweep BEFORE the boundary checkpoint: a save at an epoch edge
+        # must capture the post-sweep triple, or a resume from it would
+        # silently lose the rank change
+        self._maybe_dmrg(step + 1)
+        if self.run.train.ckpt_every and \
+                (step + 1) % self.run.train.ckpt_every == 0:
+            self._save(step + 1)
 
     # ------------------------------------------------------------------
     def losses(self) -> np.ndarray:

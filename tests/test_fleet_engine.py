@@ -186,7 +186,7 @@ def test_fleet_config_validation():
 def test_disagg_token_identical_single_device():
     """The prefill-worker pool + block handoff must be invisible in the
     output: same tokens as the co-batched engine, one decode trace
-    (the worker reuses it), and a correct latency phase split."""
+    (the worker reuses it), and per-request times in order."""
     cfg, rt = _runtime()
     reqs = _mixed_requests(cfg)
     reqs.append(Request(reqs[0].prompt, 1, task=0))  # finishes at prefill
@@ -195,7 +195,11 @@ def test_disagg_token_identical_single_device():
     assert got == ref
     st = eng.last_stats
     assert st.decode_traces == 1
-    assert st.ttft_s > 0.0 and st.tpot_s > 0.0
+    # every request's times in lifecycle order; the first token of a
+    # handed-off request comes from the prefill worker, before decode
+    for res in eng.last_results:
+        assert (res.t_submit <= res.t_admit <= res.t_first
+                <= res.t_done), res
     # the prefill worker reports as replica -1 with a handoff count:
     # 5 decode-bound requests handed off, the max_new==1 one finished
     # at prefill harvest and never touched a decode replica

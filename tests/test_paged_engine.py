@@ -230,3 +230,47 @@ def test_paged_attention_kernel_matches_ref(c, heads):
                                      backend="pallas", interpret=True)
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("mode", ["paged", "dense"])
+def test_request_times_in_lifecycle_order(mode):
+    """Every request's times come in lifecycle order: submitted, admitted
+    into a slot, first token, done (five requests through two slots, so
+    three of them queue)."""
+    cfg, spec, params = _setup()
+    rt = AdapterRuntime.build("live", params["base"], spec,
+                              params["adapter"], params["frozen"])
+    _, eng = _serve(cfg, rt, _mixed_requests(cfg), mode)
+    res = eng.last_results
+    assert len({r.t_submit for r in res}) == 1
+    for r in res:
+        assert r.t_submit <= r.t_admit <= r.t_first <= r.t_done, r
+    # the queued requests were admitted only as slots came free
+    assert max(r.t_admit for r in res) > min(r.t_done for r in res)
+
+
+def test_first_token_lands_ceil_plen_over_chunk_steps_after_admission():
+    """With no prefix hit, a request admitted into a free slot emits its
+    first token ceil(plen / prefill_chunk) loop steps after admission;
+    its ``t_first`` is that step's place inside the decode call that ran
+    it, between the call's dispatch and return."""
+    cfg, spec, params = _setup()
+    rt = AdapterRuntime.build("live", params["base"], spec,
+                              params["adapter"], params["frozen"])
+    plens, chunk = (5, 9, 13, 4, 11), 4
+    reqs = [Request(jax.random.randint(jax.random.PRNGKey(10 + i), (n,), 0,
+                                       cfg.vocab_size), 5 + i % 3, task=i % 3)
+            for i, n in enumerate(plens)]
+    _, eng = _serve(cfg, rt, reqs, "paged", prefill_chunk=chunk)
+    st = eng.last_stats
+    assert st.prefix_hit_tokens == 0
+    log = st.decode_call_log
+    assert [c[2] for c in log[1:]] == [c[3] for c in log[:-1]]
+    for n, r in zip(plens, eng.last_results):
+        # the loop's step count when the request entered its slot: the
+        # "before" of the first call dispatched after its admission
+        at = next(c[2] for c in log if c[0] >= r.t_admit)
+        target = at + -(-n // chunk)
+        t_d, t_r, s0, s1 = next(c for c in log if c[2] < target <= c[3])
+        assert r.t_first == pytest.approx(
+            t_d + (target - s0) / (s1 - s0) * (t_r - t_d), rel=0, abs=1e-9)

@@ -91,7 +91,7 @@ def test_spec_greedy_token_identical(mode):
     assert out == base
     st = eng.last_stats
     assert st.spec_k == 3
-    assert st.spec_steps > 0
+    assert st.decode_steps > 0
     assert st.draft_tokens > 0
     assert 0.0 <= st.acceptance_rate <= 1.0
 

@@ -15,6 +15,7 @@ collect the same tests.
 """
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -111,11 +112,45 @@ CASES = {
 }
 
 
+#: case -> the names its kernels' custom-call instructions carry (the
+#: ``name=`` of each ``pallas_call``): the benchmark's roofline readers
+#: find a kernel's device time by this name in a trace
+KERNEL_NAMES = {
+    "tt_linear_train": {"tt_linear"},
+    "tt_linear_decode": {"tt_linear"},
+    "tt_linear_q": {"tt_linear_w8"},
+    "tt_linear_batched_a": {"tt_linear_batched_a"},
+    "tt_linear_batched_a_q": {"tt_linear_batched_a_w8"},
+    "flash_attention": {"flash_attention"},
+    "flash_attention_fwd": {"flash_attention_fwd"},
+    "flash_attention_bwd": {"flash_attention_bwd_dq",
+                            "flash_attention_bwd_dkv"},
+    "decode_attention": {"decode_attention"},
+    "paged_decode_attention": {"paged_decode_attention"},
+    "paged_decode_attention_int8": {"paged_decode_attention"},
+}
+
+
+def _compiled_text(one_chip, name):
+    fn, specs = CASES[name]
+    return jax.jit(fn).lower(*_shapes(one_chip, *specs)).compile().as_text()
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(one_chip, name):
-    fn, specs = CASES[name]
-    compiled = jax.jit(fn).lower(*_shapes(one_chip, *specs)).compile()
-    assert "tpu_custom_call" in compiled.as_text(), name
+    assert "tpu_custom_call" in _compiled_text(one_chip, name), name
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_kernel_name_in_compiled_hlo(one_chip, name):
+    """Each kernel's instruction is named by its ``pallas_call``, not by
+    whichever jitted wrapper calls it, so renaming a wrapper cannot
+    silently empty a metric that finds the kernel by name."""
+    got = {re.sub(r"\.\d+$", "", m.group(1))
+           for m in re.finditer(r"^\s*(?:ROOT\s+)?%?(\S+) = .*"
+                                r'custom_call_target="tpu_custom_call"',
+                                _compiled_text(one_chip, name), re.M)}
+    assert got == KERNEL_NAMES[name]
 
 
 def test_chip_smoke_device_check_refuses_cpu():
