@@ -23,7 +23,7 @@ from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.flash_attention import flash_attention_bwd as _flash_bwd
 from repro.kernels.flash_attention import flash_attention_fwd as _flash_fwd
 from repro.kernels.paged_attention import (
-    paged_decode_attention as _paged_attn)
+    paged_decode_attention as _paged_attn, step_shape as _paged_step_shape)
 from repro.kernels.tt_linear import tt_linear as _tt_linear
 from repro.kernels.tt_linear import tt_linear_batched_a as _tt_linear_ba
 from repro.kernels.tt_linear import tt_linear_batched_a_w8 as _tt_ba_w8
@@ -397,9 +397,11 @@ def paged_decode_attention(q, k_cache, v_cache, tables, pos, *,
     head-major block pools; tables: (B, P) int32 logical-page ->
     physical-block map (sentinel >= N marks unallocated pages); pos: (B,).
     Returns (B, C, H, d): query c attends cache cells [0, pos[b] + c]. The
-    Pallas kernel gathers blocks in its index map (scalar-prefetched
-    table) so the gathered cache never materializes; the reference path
-    gathers explicitly — same valid set, same logical order.
+    Pallas kernel copies each live page by the scalar-prefetched table
+    into VMEM itself, so the gathered cache never materializes; each grid
+    step takes the pages and kv heads ``paged_attention.step_shape``
+    derives from the shapes. The reference path gathers explicitly — same
+    valid set, same logical order.
 
     k_scale/v_scale: optional (N, KV, 1, page) per-cell scale pools for the
     int8 KV mode — the kernel dequantizes pages in-register; the
@@ -416,7 +418,14 @@ def paged_decode_attention(q, k_cache, v_cache, tables, pos, *,
                 q, k_cache, v_cache, tables, pos).astype(q.dtype)
         return _ref.paged_decode_attention_ref(q, k_cache, v_cache,
                                                tables, pos)
-    # the kernel takes queries head-major, (B, H, C, d), like the pools
-    out = _paged_attn(q.transpose(0, 2, 1, 3), k_cache, v_cache, tables,
-                      pos, k_scale, v_scale, interpret=_interp(interpret))
-    return out.transpose(0, 2, 1, 3)
+    # the kernel takes queries grouped by kv head, (B, KV, G, C, d), like
+    # the head-major pools
+    b, c, h, d = q.shape
+    p_tab, (_, kv, page, _) = tables.shape[1], k_cache.shape
+    pps, heads = _paged_step_shape(p_tab, kv, page, d, k_cache.dtype,
+                                   k_scale is not None)
+    qg = q.reshape(b, c, kv, h // kv, d).transpose(0, 2, 3, 1, 4)
+    out = _paged_attn(qg, k_cache, v_cache, tables, pos, k_scale, v_scale,
+                      pages_per_step=pps, heads=heads,
+                      interpret=_interp(interpret))
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, c, h, d)

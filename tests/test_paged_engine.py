@@ -21,7 +21,7 @@ import pytest
 from repro import configs as registry
 from repro.config.base import KernelConfig, RunConfig, SHAPES, ServeConfig
 from repro.core import tt as ttlib
-from repro.kernels import ops
+from repro.kernels import ops, paged_attention
 from repro.models import model as M
 from repro.serving import AdapterRuntime, Engine, Request
 
@@ -208,23 +208,36 @@ def test_paged_engine_rejects_oversized_request():
         ServeConfig(cache_len=64, page_size=8, num_blocks=4).validate()
 
 
-@pytest.mark.parametrize("c,heads", [(1, (4, 4)), (4, (4, 2)),
-                                     (8, (8, 2))])
-def test_paged_attention_kernel_matches_ref(c, heads):
+@pytest.mark.parametrize("c,heads,layout", [
+    pytest.param(1, (4, 4), "mixed", id="1-heads0"),
+    pytest.param(4, (4, 2), "mixed", id="4-heads1"),
+    pytest.param(8, (8, 2), "mixed", id="8-heads2"),
+    pytest.param(8, (4, 4), "ragged_table", id="ragged_table"),
+    pytest.param(1, (4, 2), "block_edge", id="block_edge"),
+    pytest.param(8, (4, 4), "chunk_cross", id="chunk_cross"),
+    pytest.param(8, (4, 2), "idle_slot", id="idle_slot"),
+    pytest.param(4, (8, 4), "split_heads", id="split_heads"),
+    pytest.param(4, (4, 4), "spec_verify", id="spec_verify"),
+])
+def test_paged_attention_kernel_matches_ref(c, heads, layout, paged_layout):
     """kernels/paged_attention.py vs kernels/ref.py twin through the ops
-    entry point, including GQA broadcast and sentinel table entries."""
+    entry point: GQA broadcast, sentinel table entries, and the layouts of
+    tests/conftest.py's PAGED_LAYOUTS (tables that end mid-step, contexts
+    ending on a step's edge, chunks across pages, idle slots, fewer kv
+    heads a step than the pools hold, the verifier's chunk)."""
     h, kv = heads
-    b, d, n, page, p_tab = 3, 16, 12, 8, 4
+    page, tables, pos, n = paged_layout(layout)
+    b, p_tab, d = tables.shape[0], tables.shape[1], 16
+    pps, per_step = paged_attention.step_shape(p_tab, kv, page, d,
+                                               jnp.float32, False)
+    if layout == "ragged_table":
+        assert p_tab % pps
+    if layout == "split_heads":
+        assert per_step < kv
     ks = jax.random.split(jax.random.PRNGKey(c), 3)
     q = jax.random.normal(ks[0], (b, c, h, d), jnp.float32)
     kc = jax.random.normal(ks[1], (n, kv, page, d), jnp.float32)
     vc = jax.random.normal(ks[2], (n, kv, page, d), jnp.float32)
-    tables = np.full((b, p_tab), n, np.int32)     # sentinel everywhere
-    tables[0, :3] = [2, 7, 1]
-    tables[1, :2] = [4, 9]
-    tables[2, :1] = [11]
-    tables = jnp.asarray(tables)
-    pos = jnp.asarray([17, 9, 3], jnp.int32)
     ref = ops.paged_decode_attention(q, kc, vc, tables, pos, backend="ref")
     pal = ops.paged_decode_attention(q, kc, vc, tables, pos,
                                      backend="pallas", interpret=True)

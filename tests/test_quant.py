@@ -222,12 +222,24 @@ def test_w8_forward_parity_across_adapter_kinds(kind, variant, num_tasks):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("c,heads", [(1, (4, 4)), (4, (4, 2))])
-def test_int8_kv_paged_attention_kernel_matches_ref(c, heads):
+@pytest.mark.parametrize("c,heads,layout", [
+    pytest.param(1, (4, 4), "mixed", id="1-heads0"),
+    pytest.param(4, (4, 2), "mixed", id="4-heads1"),
+    pytest.param(8, (4, 4), "ragged_table", id="ragged_table"),
+    pytest.param(1, (4, 2), "block_edge", id="block_edge"),
+    pytest.param(8, (4, 4), "chunk_cross", id="chunk_cross"),
+    pytest.param(8, (4, 2), "idle_slot", id="idle_slot"),
+    pytest.param(4, (8, 4), "split_heads", id="split_heads"),
+    pytest.param(4, (4, 4), "spec_verify", id="spec_verify"),
+])
+def test_int8_kv_paged_attention_kernel_matches_ref(c, heads, layout,
+                                                    paged_layout):
     """Per-cell-scale int8 pools through the ops seam: kernel in-register
-    dequant vs explicit reference dequant, incl. GQA + sentinel pages."""
+    dequant vs explicit reference dequant, incl. GQA + sentinel pages and
+    the layouts of tests/conftest.py's PAGED_LAYOUTS."""
     h, kv = heads
-    b, d, n, page, p_tab = 3, 16, 12, 8, 4
+    page, tables, pos, n = paged_layout(layout)
+    b, d = tables.shape[0], 16
     ks = jax.random.split(jax.random.PRNGKey(c), 3)
     q = jax.random.normal(ks[0], (b, c, h, d), jnp.float32)
     kc = jax.random.normal(ks[1], (n, kv, page, d), jnp.float32)
@@ -235,12 +247,6 @@ def test_int8_kv_paged_attention_kernel_matches_ref(c, heads):
     kq, k_s = quant.quantize_kv(kc)
     vq, v_s = quant.quantize_kv(vc)
     k_s, v_s = k_s[:, :, None], v_s[:, :, None]   # (N, KV, 1, page) pools
-    tables = np.full((b, p_tab), n, np.int32)     # sentinel everywhere
-    tables[0, :3] = [2, 7, 1]
-    tables[1, :2] = [4, 9]
-    tables[2, :1] = [11]
-    tables = jnp.asarray(tables)
-    pos = jnp.asarray([17, 9, 3], jnp.int32)
     want = ops.paged_decode_attention(q, kq, vq, tables, pos, k_scale=k_s,
                                       v_scale=v_s, backend="ref")
     got = ops.paged_decode_attention(q, kq, vq, tables, pos, k_scale=k_s,

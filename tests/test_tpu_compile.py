@@ -62,6 +62,26 @@ def _shapes(one_chip, *specs):
 
 bf, f32, i8, i32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 _POOL, _SPOOL = (NBLK, KV, PAGE, HD), (NBLK, KV, 1, PAGE)
+#: stablelm-serve-decode's paged shapes: 32 slots over 640 blocks of 16, a
+#: table of 65 pages (cache_len 1024 + 16), chunk 8; the verifier's chunk
+#: is spec_k + 1 with spec_k 3
+SERVE_SLOTS, SERVE_NBLK, SERVE_PTAB, VERIFY_CHUNK = 32, 640, 65, 4
+_SERVE_POOL = (SERVE_NBLK, KV, PAGE, HD)
+_SERVE_SPOOL = (SERVE_NBLK, KV, 1, PAGE)
+
+
+def _paged_serve(chunk, pool_dtype):
+    """The paged kernel case at the serving cell's shapes."""
+    specs = [((SERVE_SLOTS, chunk, H, HD), bf), (_SERVE_POOL, pool_dtype),
+             (_SERVE_POOL, pool_dtype), ((SERVE_SLOTS, SERVE_PTAB), i32),
+             ((SERVE_SLOTS,), i32)]
+    if pool_dtype == i8:
+        return (lambda q, k, v, t, p, ks, vs: ops.paged_decode_attention(
+            q, k, v, t, p, k_scale=ks, v_scale=vs, **NAT),
+            specs + [(_SERVE_SPOOL, f32), (_SERVE_SPOOL, f32)])
+    return (lambda q, k, v, t, p: ops.paged_decode_attention(
+        q, k, v, t, p, **NAT), specs)
+
 
 #: name -> (fn, argument (shape, dtype) list); the model's own call shapes
 CASES = {
@@ -109,6 +129,9 @@ CASES = {
         [((SLOTS, CHUNK, H, HD), bf), (_POOL, i8), (_POOL, i8),
          ((SLOTS, PTAB), i32), ((SLOTS,), i32), (_SPOOL, f32),
          (_SPOOL, f32)]),
+    "paged_decode_attention_serve": _paged_serve(CHUNK, bf),
+    "paged_decode_attention_serve_int8": _paged_serve(CHUNK, i8),
+    "paged_decode_attention_verify": _paged_serve(VERIFY_CHUNK, bf),
 }
 
 
@@ -128,6 +151,9 @@ KERNEL_NAMES = {
     "decode_attention": {"decode_attention"},
     "paged_decode_attention": {"paged_decode_attention"},
     "paged_decode_attention_int8": {"paged_decode_attention"},
+    "paged_decode_attention_serve": {"paged_decode_attention"},
+    "paged_decode_attention_serve_int8": {"paged_decode_attention"},
+    "paged_decode_attention_verify": {"paged_decode_attention"},
 }
 
 
