@@ -31,7 +31,7 @@ for _p in (ROOT, os.path.join(ROOT, "src")):
 
 def control(cell, seed: int, sample) -> dict:
     from bench.lib import cells, compare
-    mod = cells.reference(cell)
+    mod = cells.architecture(cell)
     adapter = cell.traffic["adapter"]
     ref = mod.Reference(cell.config, adapter, seed)
     low = mod.Reference(cell.config, adapter, seed, quant="fp8")

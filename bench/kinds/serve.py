@@ -19,7 +19,7 @@ import time
 
 import numpy as np
 
-from bench.lib import flops, peaks, program, report, tracing
+from bench.lib import cells, peaks, program, report, tracing
 from bench.lib.report import log as _log
 from bench.lib import traffic as traffic_lib
 
@@ -32,14 +32,16 @@ def _generate(eng, reqs, deadline_s: float):
     return outs, time.perf_counter() - t0
 
 
-def _work(cfg, adapter, reqs, results, chunk: int) -> dict:
-    """Required work of the requests, as far as their results show it: a
-    request that produced tokens had its whole prompt computed; one cut
-    before its first token shows nothing and is left out."""
+def _work(arch, cfg, adapter, reqs, eng) -> dict:
+    """Required work of the requests of ``eng``'s last call, as far as
+    their results show it: a request that produced tokens had its whole
+    prompt computed; one cut before its first token shows nothing and is
+    left out."""
     rows = [(len(r.prompt), int(res.n_generated),
              len(r.prompt) if res.n_generated > 0 else 0)
-            for r, res in zip(reqs, results)]
-    return flops.serve_forward(cfg, adapter, rows, chunk)
+            for r, res in zip(reqs, eng.last_results)]
+    return arch.work.serve_forward(cfg, adapter, rows, eng.sv.prefill_chunk,
+                                   arch.seam.counts(eng))
 
 
 def warmup_requests(traffic: dict, vocab: int, seed: int, slots: int):
@@ -111,10 +113,11 @@ def run(cell, args, t_start: float, devs) -> dict:
     adapter, eng_cfg = traffic["adapter"], traffic["engine"]
     vocab, seed = cfg["vocab_size"], args.seed
     st_names = program.statuses()
+    arch = cells.architecture(cell)
     t_in = time.perf_counter()
 
-    p = program.params(cfg, adapter, seed)
-    eng = program.engine(cfg, adapter, p, eng_cfg)
+    p = arch.seam.params(cfg, adapter, seed)
+    eng = program.engine(arch.seam.model_config(cfg), adapter, p, eng_cfg)
     del p
     t_eng = time.perf_counter()
     warm = warmup_requests(traffic, vocab, seed, eng_cfg["max_batch"])
@@ -141,7 +144,6 @@ def run(cell, args, t_start: float, devs) -> dict:
     attempted = by["finished"] + by["failed"] + by["cancelled"]
     failed = by["failed"] + by["cancelled"] + max(
         0, st.numerics_faults - by["failed"])
-    chunk = eng.sv.prefill_chunk
     e2e = {"serve_tokens_per_s": tokens / wall, "setup_s": setup_s}
     ctx = {"kind": "serve", "cfg": cfg, "traffic": traffic,
            "peak": peaks.peak(devs[0].device_kind),
@@ -149,7 +151,7 @@ def run(cell, args, t_start: float, devs) -> dict:
            "window": {"seconds": wall, "tokens": tokens,
                       "stats": dict(decode_calls=st.decode_calls,
                                     tokens=st.tokens_generated),
-                      "work": _work(cfg, adapter, reqs, results, chunk)}}
+                      "work": _work(arch, cfg, adapter, reqs, eng)}}
     breakdown = None
     tr_cfg = traffic["trace"]
     if args.trace:
@@ -167,8 +169,7 @@ def run(cell, args, t_start: float, devs) -> dict:
              f" requests finished, decode_calls "
              f"{eng.last_stats.decode_calls}")
         ctx["trace"] = tr
-        ctx["traced"] = {"work": _work(cfg, adapter, treqs,
-                                       eng.last_results, chunk)}
+        ctx["traced"] = {"work": _work(arch, cfg, adapter, treqs, eng)}
         breakdown = tracing.breakdown(tr)
     device = report.device_info(devs, cell.chips)
     if args.trace:
@@ -187,9 +188,7 @@ def run(cell, args, t_start: float, devs) -> dict:
          f"use {report.bytes_in_use(devs[0])}")
     lim = cell.limits
     if pick:
-        from bench.lib import cells as cells_lib
-        ref_mod = cells_lib.reference(cell)
-        ref = ref_mod.Reference(cfg, adapter, seed)
+        ref = arch.Reference(cfg, adapter, seed)
         t0 = time.perf_counter()
         gaps, agree = served_gaps(ref, ref.cores(), prompts, served, tasks)
         _log(f"reference {time.perf_counter() - t0:.1f}s; argmax "

@@ -1,10 +1,11 @@
 """A training cell: ``Trainer.train`` stepping a MetaTT adapter on a frozen
 base, one step per call, for ``--seconds``.
 
-Set-up builds the Trainer (weights from the seed, handed to the program),
-and drives it through its first steps with the window's own call and feed;
-those steps compile the step and are the ones the reference follows. The
-window then keeps stepping the same Trainer. The rate counts every token of
+Set-up builds the Trainer (weights from the seed, made by the cell's
+architecture and handed to the program), and drives it through its first
+steps with the window's own call and feed; those steps compile the step and
+are the ones the reference follows. The window then keeps stepping the same
+Trainer. The rate counts every token of
 every step in the window over the window's whole wall time, batch making
 included.
 
@@ -19,7 +20,7 @@ import time
 
 import numpy as np
 
-from bench.lib import compare, flops, peaks, program, report, tracing
+from bench.lib import cells, compare, peaks, program, report, tracing
 from bench.lib.report import log as _log
 from bench.lib import traffic as traffic_lib
 
@@ -55,12 +56,14 @@ def run(cell, args, t_start: float, devs) -> dict:
     adapter, train = traffic["adapter"], traffic["train"]
     seed, steps = args.seed, traffic["check"]["steps"]
     d = traffic["data"]
+    arch = cells.architecture(cell)
     t_in = time.perf_counter()
 
-    p = program.params(cfg, adapter, seed)
+    p = arch.seam.params(cfg, adapter, seed)
     feed = feed_for(cfg, traffic, seed,
                     on_batch=lambda: tracing.span("make_batch"))
-    tr = program.trainer(cfg, adapter, p, train, feed)
+    tr = program.trainer(arch.seam.model_config(cfg), adapter, p, train,
+                         feed)
     del p
     t_tr = time.perf_counter()
     prog = first_steps(tr, steps, train["optimizer"]["betas"][0])
@@ -84,13 +87,20 @@ def run(cell, args, t_start: float, devs) -> dict:
     failed = sum(not np.isfinite(m["loss"]) for _, m in done)
     _log(f"window {wall:.2f}s: {len(done)} steps, step times "
          f"{[round(m['step_time_s'], 3) for _, m in done]}")
-    work = flops.train_step(cfg, adapter, d["batch"], d["seq_len"])
+
+    def step_work(k: int) -> dict:
+        """``k`` steps' work: one step's, from the shapes and the counters
+        of the program's last call, times ``k``."""
+        one = arch.work.train_step(cfg, adapter, d["batch"], d["seq_len"],
+                                   arch.seam.counts(tr))
+        return {key: v * k for key, v in one.items()}
+
     e2e = {"train_tokens_per_s": tok / wall, "setup_s": setup_s}
     ctx = {"kind": "train", "cfg": cfg, "traffic": traffic,
            "peak": peaks.peak(devs[0].device_kind),
            "device_kind": devs[0].device_kind,
            "window": {"seconds": wall, "tokens": tok, "steps": len(done),
-                      "work": {k: v * len(done) for k, v in work.items()}}}
+                      "work": step_work(len(done))}}
     breakdown = None
     if args.trace:
         k = traffic["trace"]["steps"]
@@ -99,7 +109,7 @@ def run(cell, args, t_start: float, devs) -> dict:
                 with tracing.span("train_step"):
                     tr.train(int(tr.state.step) + 1)
         ctx["trace"] = held.trace
-        ctx["traced"] = {"work": {k2: v * k for k2, v in work.items()}}
+        ctx["traced"] = {"work": step_work(k)}
         breakdown = tracing.breakdown(held.trace)
     device = report.device_info(devs, cell.chips)
     if args.trace:
@@ -110,8 +120,7 @@ def run(cell, args, t_start: float, devs) -> dict:
     _log(f"device bytes in use before the reference "
          f"{report.bytes_in_use(devs[0])}")
 
-    from bench.lib import cells as cells_lib
-    ref = cells_lib.reference(cell).Reference(cfg, adapter, seed)
+    ref = arch.Reference(cfg, adapter, seed)
     same = all(np.array_equal(a, np.asarray(b))
                for a, b in zip(prog["start"], ref.cores()))
     t0 = time.perf_counter()

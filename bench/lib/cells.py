@@ -1,16 +1,19 @@
 """Finds a cell's pieces by the names in ``BENCHMARK.json``: its
 configuration file, its traffic mix (``bench/traffic/<traffic>.json``), its
 limits (``bench/limits/<cell>.json``), its kind of run
-(``bench/kinds/<kind>.py``, named by the traffic file), its reference
-(``bench/models/<reference>.py``, named by the configuration file) and the
+(``bench/kinds/<kind>.py``, named by the traffic file), its architecture
+(the package ``bench/models/<architecture>/``, named by the configuration
+file: weights, reference, work counts and the program's seam) and the
 readers of its per-layer metrics (``bench/metrics/<metric>.py``). Adding a
-cell, a mix or a metric adds files and entries; no file here changes."""
+cell, a mix, a metric or an architecture adds files and entries; no file
+here changes."""
 from __future__ import annotations
 
 import dataclasses
 import importlib.util
 import json
 import os
+import sys
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -75,8 +78,32 @@ def kind(cell: Cell):
     return _module(cell.root, "kinds", cell.traffic["kind"])
 
 
-def reference(cell: Cell):
-    return _module(cell.root, "models", cell.config["reference"])
+def _package(root: str, name: str):
+    """The package ``bench/models/<name>/`` under ``root``, loaded as a
+    package of its own (its files import one another relatively), once per
+    directory."""
+    path = os.path.join(root, "bench", "models", name)
+    key = "bench_models_" + "".join(
+        c if c.isalnum() else "_" for c in os.path.abspath(path))
+    if key not in sys.modules:
+        spec = importlib.util.spec_from_file_location(
+            key, os.path.join(path, "__init__.py"),
+            submodule_search_locations=[path])
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[key] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[key]
+            raise
+    return sys.modules[key]
+
+
+def architecture(cell: Cell):
+    """The cell's architecture: the package that its configuration file's
+    ``architecture`` key names, with ``Reference``, ``weights``, ``work``
+    and ``seam``."""
+    return _package(cell.root, cell.config["architecture"])
 
 
 def metric_reader(root: str, name: str):

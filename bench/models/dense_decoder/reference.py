@@ -3,8 +3,8 @@
 Straight ``jax.numpy`` in float32 at ``highest`` matmul precision, one layer
 at a time, with no kernel, cache or batching of the program under test. It
 imports nothing of that program: the weights come from the benchmark's own
-generator (``bench.lib.weights``), made again from the seed layer by layer,
-so that no more than one layer's weights are held at once.
+generator (``weights.py`` beside this file), made again from the seed layer
+by layer, so that no more than one layer's weights are held at once.
 
 The block (the repo's dense transformer path; a configuration file's
 ``runs_as`` and ``departures`` say where it differs from the published
@@ -20,7 +20,7 @@ model):
 The adapter on matrix m of layer l (task t for the 4+1d variant) adds
     alpha * x G1[:d_in] C[l, (t,) m] G_last[:, :d_out],
     C[l, (t,) m] = G2[:, l] (G3[:, t]) G_m[:, m],
-with the TT cores of ``bench.lib.weights.tt_cores``.
+with the TT cores of ``weights.cores``.
 
 ``quant`` selects the precision: ``None`` for the float32 reference;
 ``"fp8"`` (the control) rounds every matmul operand to float8 e4m3 with a
@@ -36,7 +36,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from bench.lib import weights as W
+from bench.lib.weights import seed_key
+
+from . import weights as W
 
 HI = jax.lax.Precision.HIGHEST
 #: the block above, in the configuration files' keys
@@ -219,11 +221,11 @@ class Reference:
         if other:
             raise ValueError(f"{cfg['name']}: this reference has no {other}")
         self.cfg, self.adapter, self.quant = cfg, adapter, quant
-        self.key = W.seed_key(seed)
+        self.key = seed_key(seed)
         self.L = cfg["num_hidden_layers"]
         self._layer_w = jax.jit(functools.partial(W.layer, cfg))
         self._embed = jax.jit(functools.partial(W.embed, cfg))
-        self._cores = jax.jit(functools.partial(W.tt_cores, cfg, adapter))
+        self._cores = jax.jit(functools.partial(W.cores, cfg, adapter))
         self._fwd = jax.jit(functools.partial(
             _layer_static, cfg=cfg, adapter=adapter, quant=quant))
         self._bwd = jax.jit(functools.partial(

@@ -6,7 +6,8 @@ import os
 
 import pytest
 
-from bench.lib import flops, peaks
+from bench.lib import peaks
+from bench.models.dense_decoder import work as flops
 from bench.tests.tiny import ROOT
 
 
@@ -50,7 +51,7 @@ def test_attention_and_head_by_hand():
 
 def test_train_step_by_hand():
     ad = _traffic("train-4k")["adapter"]
-    w = flops.train_step(MISTRAL, ad, 1, 4096)
+    w = flops.train_step(MISTRAL, ad, 1, 4096, {})
     mat, a = 2 * 1_384_120_320, flops.adapter_per_token(MISTRAL, ad)
     att = 4 * 96 * 128 * 4096 * 4097 / 2
     head = 2 * 12288 * 32768
@@ -69,7 +70,8 @@ def test_serve_forward_by_hand():
     # one request: prompt 3, 2 tokens generated, prefill chunk 2.
     # positions 0, 1, 2 (prompt) and 3 (first token fed back) run forward;
     # two readouts; contexts 1..4
-    w = flops.serve_forward(STABLELM, ad, [(3, 2, 3)], chunk=2)
+    w = flops.serve_forward(STABLELM, ad, [(3, 2, 3)], chunk=2,
+                            counts={})
     per = 24 * (flops.matmul_per_token(STABLELM)
                 + flops.adapter_per_token(STABLELM, ad))
     att = 24 * 4 * 32 * 64 * (1 + 2 + 3 + 4)
@@ -81,7 +83,7 @@ def test_serve_forward_by_hand():
     assert w["paged_bytes"] == pytest.approx(
         24 * ((2 + 3 + 4) * kv_row + 4 * q_row))
     # a request cut before its first token shows no work
-    assert flops.serve_forward(STABLELM, ad, [(100, 0, 0)], 8)[
+    assert flops.serve_forward(STABLELM, ad, [(100, 0, 0)], 8, {})[
         "model_flops"] == 0
 
 

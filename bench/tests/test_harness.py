@@ -7,7 +7,9 @@ import sys
 import numpy as np
 import pytest
 
-from bench.lib import cells, traffic, weights
+from bench.lib import cells, traffic
+from bench.lib.weights import seed_key
+from bench.models.dense_decoder import weights
 from bench.tests.tiny import ROOT
 
 
@@ -55,7 +57,7 @@ def test_every_real_cell_resolves():
         assert len(cell.end_to_end) >= 2 and cell.per_layer
         for m in cell.per_layer:
             assert callable(cells.metric_reader(ROOT, m["name"]))
-        assert cells.reference(cell).Reference
+        assert cells.architecture(cell).Reference
 
 
 def test_same_seed_same_requests():
@@ -92,26 +94,25 @@ def test_configs_hold_the_source_and_state_what_runs():
     """A configuration file holds the source's keys; what the program runs
     otherwise is under ``runs_as``, and without it neither the program's
     seam nor the reference takes the file."""
-    from bench.lib import program
     spec = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     for c in spec["configs"]:
         raw = json.load(open(os.path.join(ROOT, c["file"])))
         assert set(c["reduced"]) <= set(raw) and not (
             set(c["reduced"]) & set(raw["runs_as"]))
         run = cells.run_config(raw)
-        program.model_config(run)
+        cells._package(ROOT, raw["architecture"]).seam.model_config(run)
         changed = {k for k in raw["runs_as"] if raw[k] != run[k]}
         assert changed == set(raw["runs_as"])
     raw = json.load(open(os.path.join(
         ROOT, "bench/configs/stablelm-1.6b.json")))
     assert raw["partial_rotary_factor"] == 0.25 and raw["use_qkv_bias"]
-    ref = cells._module(ROOT, "models", raw["reference"])
+    arch = cells._package(ROOT, raw["architecture"])
     tr = json.load(open(os.path.join(ROOT, "bench/traffic/train-8k.json")))
     for bad in (raw, dict(cells.run_config(raw), use_qkv_bias=True)):
         with pytest.raises(ValueError):
-            program.model_config(bad)
+            arch.seam.model_config(bad)
         with pytest.raises(ValueError):
-            ref.Reference(bad, tr["adapter"], 1)
+            arch.Reference(bad, tr["adapter"], 1)
 
 
 def test_same_seed_same_batches():
@@ -143,7 +144,7 @@ def test_one_layer_matches_the_stack():
         num_key_value_heads=2, head_dim=8, vocab_size=64,
         num_hidden_layers=3)
     tr = json.load(open(os.path.join(ROOT, "bench/traffic/train-4k.json")))
-    key = weights.seed_key(2 ** 31 + 9)
+    key = seed_key(2 ** 31 + 9)
     st = jax.jit(lambda k: weights.stacked(cfg, tr["adapter"], k))(key)
     one = weights.layer(cfg, key, 2)
     for k, v in one.items():

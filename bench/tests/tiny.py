@@ -1,9 +1,12 @@
 """A copy of the benchmark with tiny cells, for runs on the CPU.
 
-``make(dst)`` copies ``bench/`` into ``dst`` and writes into the copy a
-``BENCHMARK.json`` whose cells are the real ones cut to a tiny size (every
-key of each configuration, traffic and limits file as in the real cell,
-with the sizes below in their place). The real cells' limits are kept."""
+``make(dst, src)`` copies ``bench/`` of ``src`` into ``dst`` and writes into
+the copy a ``BENCHMARK.json`` whose cells are ``src``'s cells cut to a tiny
+size: every key of each configuration, traffic and limits file as in the
+real cell, with the sizes of ``bench/tests/tiny/<cell>.json`` in their
+place (``{"config": {...}, "traffic": {...}}``). The real cells' limits are
+kept. Every cell has such a file; a new cell gets its CPU runs by adding
+one."""
 from __future__ import annotations
 
 import json
@@ -13,24 +16,6 @@ import shutil
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH)
-
-TINY_MODEL = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
-                  vocab_size=256)
-TINY = {  # cell -> (config sizes, traffic sizes)
-    "stablelm-serve-decode": (
-        dict(num_attention_heads=4, num_key_value_heads=4),
-        dict(engine={"runtime": "live", "max_batch": 4, "cache_len": 64,
-                     "out_cap": 32, "num_blocks": 64},
-             prompt={"dist": "lognormal", "median": 12, "sigma": 0.5,
-                     "min": 8, "max": 24},
-             output={"dist": "lognormal", "median": 8, "sigma": 0.5,
-                     "min": 4, "max": 16},
-             requests=24, warmup_seconds=0.5,
-             check={"tokens": 60, "max_requests": 8})),
-    "stablelm-train-8k": (
-        dict(num_attention_heads=4, num_key_value_heads=4),
-        dict(data={"seq_len": 32, "batch": 2, "branching": 4})),
-}
 
 
 def _load(path):
@@ -43,22 +28,21 @@ def _dump(obj, path):
         json.dump(obj, f, indent=1)
 
 
-def make(dst: str) -> str:
-    shutil.copytree(BENCH, os.path.join(dst, "bench"),
+def make(dst: str, src: str = ROOT) -> str:
+    shutil.copytree(os.path.join(src, "bench"), os.path.join(dst, "bench"),
                     ignore=shutil.ignore_patterns("__pycache__"))
-    spec = _load(os.path.join(ROOT, "BENCHMARK.json"))
+    spec = _load(os.path.join(src, "BENCHMARK.json"))
     confs = {c["name"]: c for c in spec["configs"]}
     for w in spec["workloads"]:
-        if w["name"] not in TINY:
-            continue
-        msize, tsize = TINY[w["name"]]
+        size = _load(os.path.join(src, "bench", "tests", "tiny",
+                                  w["name"] + ".json"))
         conf = confs[w["config"]]
-        cfg = _load(os.path.join(ROOT, conf["file"]))
-        cfg.update(TINY_MODEL, **msize)
+        cfg = _load(os.path.join(src, conf["file"]))
+        cfg.update(size["config"])
         _dump(cfg, os.path.join(dst, conf["file"]))
         tpath = os.path.join(dst, "bench", "traffic", w["traffic"] + ".json")
         tr = _load(tpath)
-        tr.update(tsize)
+        tr.update(size["traffic"])
         _dump(tr, tpath)
     _dump(spec, os.path.join(dst, "BENCHMARK.json"))
     return dst
