@@ -19,8 +19,21 @@ from typing import Any, Mapping, Optional
 
 import jax.numpy as jnp
 
-MIXERS = ("attn", "mamba", "mlstm", "slstm", "none")
+MIXERS = ("attn", "mla", "mamba", "mlstm", "slstm", "none")
 FFNS = ("dense", "moe", "none")
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN rope scaling in the DeepSeek-V3 form (``rope_scaling`` of type
+    "yarn"); models/attention.py::yarn_inv_freq and ``yarn_softmax_scale``
+    write its equations down."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,11 +53,37 @@ class ModelConfig:
     norm_eps: float = 1e-5
     rope_theta: float = 10000.0
     tie_embeddings: bool = True
+    # --- multi-head latent attention (mixer "mla", models/attention.py) ---
+    q_lora_rank: int = 0           # query down-projection width
+    kv_lora_rank: int = 0          # key/value latent width
+    qk_nope_head_dim: int = 0      # per-head q/k dims without rope
+    qk_rope_head_dim: int = 0      # per-head q dims with rope; k's is shared
+    v_head_dim: int = 0
+    rope_scaling: Optional[YaRN] = None
+    # --- leading dense layers ahead of the scanned pattern ---
+    first_dense_layers: int = 0    # layers 0..n-1: (mixer, dense SwiGLU)
+    dense_d_ff: int = 0            # their width (d_ff is the expert width)
     # --- MoE ---
     num_experts: int = 0
     experts_per_token: int = 0
     num_shared_experts: int = 0    # kimi-style always-on shared expert(s)
     moe_capacity_factor: float = 2.0  # GShard capacity dispatch (models/moe.py)
+    # the router (the published ``scoring_func``): "softmax" top-k,
+    # renormalised; "sigmoid": DeepSeek-V3's noaux_tc, sigmoid scores chosen
+    # with a per-expert correction bias (models/moe.py::sigmoid_router)
+    moe_scoring: str = "softmax"
+    # "capacity": GShard slots, overflow dropped; "dropless": every pair
+    # computed through a grouped matmul (models/moe.py::dropless_block)
+    moe_dispatch: str = "capacity"
+    # the chosen experts' weights are scaled by it (routed_scaling_factor)
+    moe_routed_scale: float = 1.0
+    # the step also returns each expert layer's chosen experts
+    # (``moe_routing``, models/moe.py::RECORDS)
+    moe_record_routing: bool = False
+    # the experts this chip holds: [expert_offset, expert_offset +
+    # experts_held) of num_experts (0 -> all); the router still scores all
+    experts_held: int = 0
+    expert_offset: int = 0
     # load-balance/z losses train the ROUTER — which is frozen under PEFT, so
     # they only add compute + a 0.2TB/step probs gather (kimi dry-run, §Perf
     # iteration K3). 0 disables them; set >0 for full fine-tuning.
@@ -99,11 +138,29 @@ class ModelConfig:
 
     @property
     def num_super_blocks(self) -> int:
-        if self.num_layers % self.pattern_len:
+        """Scanned super-blocks: the layers after the leading dense ones,
+        over the pattern length."""
+        n = self.num_layers - self.first_dense_layers
+        if n % self.pattern_len:
             raise ValueError(
-                f"{self.name}: num_layers={self.num_layers} not divisible by "
+                f"{self.name}: {n} scanned layers not divisible by "
                 f"pattern length {self.pattern_len}")
-        return self.num_layers // self.pattern_len
+        return n // self.pattern_len
+
+    @property
+    def is_mla(self) -> bool:
+        return any(m == "mla" for m, _ in self.block_pattern)
+
+    @property
+    def qk_head_dim(self) -> int:
+        """MLA's per-head q/k width: the rope-free part plus the rope
+        part."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def local_experts(self) -> int:
+        """Experts whose weights this model holds."""
+        return self.experts_held or self.num_experts
 
     @property
     def is_encdec(self) -> bool:
@@ -122,6 +179,19 @@ class ModelConfig:
         if any(f == "moe" for _, f in self.block_pattern):
             if not (self.num_experts and self.experts_per_token):
                 raise ValueError(f"{self.name}: moe blocks need num_experts")
+        if self.moe_dispatch not in ("capacity", "dropless"):
+            raise ValueError(f"moe_dispatch {self.moe_dispatch!r}")
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_scoring {self.moe_scoring!r}")
+        if self.expert_offset + self.local_experts > self.num_experts:
+            raise ValueError(f"{self.name}: held experts out of range")
+        if self.is_mla and not (self.q_lora_rank and self.kv_lora_rank
+                                and self.qk_rope_head_dim
+                                and self.v_head_dim):
+            raise ValueError(f"{self.name}: mla needs its ranks and dims")
+        if self.first_dense_layers and not self.dense_d_ff:
+            raise ValueError(f"{self.name}: leading dense layers need "
+                             "dense_d_ff")
         return self
 
 

@@ -263,40 +263,84 @@ def tt_linear_batched_a(x, w, a, b, *, alpha: float = 1.0,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _fused_flash(pol: KernelPolicy, causal: bool, q, k, v):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _fused_flash(pol: KernelPolicy, causal: bool, scale, q, k, v):
     return ops.flash_attention(q, k, v, causal=causal, backend="pallas",
                                interpret=pol.interpret, bq=pol.bq,
-                               bkv=pol.bkv)
+                               bkv=pol.bkv, scale=scale)
 
 
-def _fused_flash_fwd(pol, causal, q, k, v):
+def _fused_flash_fwd(pol, causal, scale, q, k, v):
     # the stats-emitting forward: one extra (B, H, T) f32 residual (lse)
     # buys a backward that never builds (T, S)
     out, lse = ops.flash_attention_fwd(q, k, v, causal=causal,
                                        backend="pallas",
                                        interpret=pol.interpret, bq=pol.bq,
-                                       bkv=pol.bkv)
+                                       bkv=pol.bkv, scale=scale)
     return out, (q, k, v, out, lse)
 
 
-def _fused_flash_bwd(pol, causal, res, g):
+def _fused_flash_bwd(pol, causal, scale, res, g):
     q, k, v, out, lse = res
     return ops.flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
                                    backend="pallas",
                                    interpret=pol.interpret, bq=pol.bq,
-                                   bkv=pol.bkv)
+                                   bkv=pol.bkv, scale=scale)
 
 
 _fused_flash.defvjp(_fused_flash_fwd, _fused_flash_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
-                    policy: Optional[KernelPolicy] = None):
-    """GQA attention. q: (B, T, H, d); k, v: (B, S, KV, d) -> (B, T, H, d)."""
+                    policy: Optional[KernelPolicy] = None,
+                    scale: Optional[float] = None):
+    """GQA attention. q: (B, T, H, d); k: (B, S, KV, d); v: (B, S, KV, dv)
+    -> (B, T, H, dv). ``scale`` multiplies q·kᵀ (None -> d^-0.5)."""
     if policy is not None and policy.flash_attn:
-        return _fused_flash(policy, causal, q, k, v)
-    return ops.flash_attention(q, k, v, causal=causal, backend="ref")
+        return _fused_flash(policy, causal, scale, q, k, v)
+    return ops.flash_attention(q, k, v, causal=causal, backend="ref",
+                               scale=scale)
+
+
+# ---------------------------------------------------------------------------
+# expert-grouped matmul (the dropless expert layer, models/moe.py)
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _gmm(pol: KernelPolicy, tm: int, transpose_w: bool, x, w, tile_group,
+         n_used):
+    return ops.grouped_matmul(x, w, tile_group, n_used, tm=tm,
+                              transpose_w=transpose_w,
+                              backend="pallas" if pol.use_pallas else "ref",
+                              interpret=pol.interpret)
+
+
+def _gmm_fwd(pol, tm, transpose_w, x, w, tile_group, n_used):
+    return _gmm(pol, tm, transpose_w, x, w, tile_group, n_used), \
+        (w, tile_group, n_used)
+
+
+def _gmm_bwd(pol, tm, transpose_w, res, g):
+    # dx only: dx[tile i] = g[tile i]·w[e]ᵀ is the same grouped matmul with
+    # the weights transposed. The expert weights are frozen base weights,
+    # so no weight gradient is formed.
+    w, tile_group, n_used = res
+    dx = _gmm(pol, tm, not transpose_w, g, w, tile_group, n_used)
+    return dx, None, None, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def grouped_matmul(x, w, tile_group, n_used, *, tm: int,
+                   transpose_w: bool = False,
+                   policy: Optional[KernelPolicy] = None):
+    """Expert-grouped matmul of ``tm``-aligned sorted rows (see
+    ``ops.grouped_matmul``), differentiable in x. The Pallas kernel
+    (``moe_gmm``) when the policy routes to Pallas, else its XLA twin."""
+    pol = policy if policy is not None else REF
+    return _gmm(pol, tm, transpose_w, x, w, tile_group, n_used)
 
 
 def decode_attention(q, k, v, pos, *,

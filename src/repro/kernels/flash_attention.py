@@ -22,8 +22,11 @@ Strictly-above-diagonal tiles are skipped under causal masking (the
 
 Layout: (B·H, T, d) per head — GQA callers broadcast kv heads before the
 call and reduce dk/dv over the head group after it (ops.py). d is kept
-whole per tile (d ≤ 256 across the zoo). Validated in interpret mode
-against kernels/ref.py::flash_attention_ref / flash_attention_bwd_ref.
+whole per tile (d ≤ 256 across the zoo). The value head dim dv may differ
+from the q/k head dim d (latent attention: d 192, dv 128), and the softmax
+scale may be the caller's (YaRN's); by default it is ``d ** -0.5``.
+Validated in interpret mode against kernels/ref.py::flash_attention_ref /
+flash_attention_bwd_ref.
 """
 from __future__ import annotations
 
@@ -88,24 +91,33 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale: float, causal: bool,
             lse_ref[0] = (m_ref[...] + jnp.log(l))[None, :]
 
 
+def _scale(d: int, scale) -> float:
+    """The softmax scale: the caller's, or ``d ** -0.5`` of the q/k head
+    dim."""
+    return d ** -0.5 if scale is None else float(scale)
+
+
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bkv",
-                                             "interpret", "kv_len"))
+                                             "interpret", "kv_len", "scale"))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     causal: bool = True, bq: int = 256, bkv: int = 256,
-                    interpret: bool = True, kv_len: int = 0) -> jnp.ndarray:
-    """q: (BH, T, d); k, v: (BH, S, d) -> (BH, T, d).
+                    interpret: bool = True, kv_len: int = 0,
+                    scale: float | None = None) -> jnp.ndarray:
+    """q, k: (BH, T|S, d); v: (BH, S, dv) -> (BH, T, dv).
 
+    ``dv`` may differ from the q/k head dim ``d`` (latent attention: 192
+    for q/k, 128 for v); ``scale`` multiplies q·kᵀ (None -> ``d ** -0.5``).
     kv_len: number of *real* key/value rows (0 -> S). Callers that pad S up
     to a bkv multiple pass the unpadded length so the tail keys are masked
     out of the softmax (zero-padded keys would otherwise contribute
     exp(0) mass under non-causal attention).
     """
     bh, t, d = q.shape
-    s_len = k.shape[1]
+    s_len, dv = k.shape[1], v.shape[2]
     assert t % bq == 0 and s_len % bkv == 0, (t, s_len, bq, bkv)
     grid = (bh, t // bq, s_len // bkv)
     kernel = functools.partial(
-        _kernel, scale=d ** -0.5, causal=causal, bq=bq, bkv=bkv,
+        _kernel, scale=_scale(d, scale), causal=causal, bq=bq, bkv=bkv,
         kv_steps=grid[2], kv_len=kv_len or s_len)
     return pl.pallas_call(
         kernel,
@@ -114,14 +126,14 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bkv, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bkv, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bkv, dv), lambda b, i, j: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -130,22 +142,23 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bkv",
-                                             "interpret", "kv_len"))
+                                             "interpret", "kv_len", "scale"))
 def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                         causal: bool = True, bq: int = 256, bkv: int = 256,
-                        interpret: bool = True, kv_len: int = 0):
+                        interpret: bool = True, kv_len: int = 0,
+                        scale: float | None = None):
     """Stats-emitting forward for training: same kernel as
     ``flash_attention`` plus a second output carrying the per-row
     log-sum-exp — the residual the blockwise backward rebuilds probability
-    tiles from. Returns ``(out (BH, T, d), lse (BH, 1, T) f32)`` — the
+    tiles from. Returns ``(out (BH, T, dv), lse (BH, 1, T) f32)`` — the
     row statistic lies along lanes, so its (1, 1, bq) blocks meet the
     TPU's block rule (last two block dims whole or (8, 128)-aligned)."""
     bh, t, d = q.shape
-    s_len = k.shape[1]
+    s_len, dv = k.shape[1], v.shape[2]
     assert t % bq == 0 and s_len % bkv == 0, (t, s_len, bq, bkv)
     grid = (bh, t // bq, s_len // bkv)
     kernel = functools.partial(
-        _kernel, scale=d ** -0.5, causal=causal, bq=bq, bkv=bkv,
+        _kernel, scale=_scale(d, scale), causal=causal, bq=bq, bkv=bkv,
         kv_steps=grid[2], kv_len=kv_len or s_len, with_stats=True)
     return pl.pallas_call(
         kernel,
@@ -154,20 +167,20 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bkv, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bkv, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bkv, dv), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -202,7 +215,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
     def _block():
         q = q_ref[0]                                   # (bq, d)
         k = k_ref[0]                                   # (bkv, d)
-        g = g_ref[0]                                   # (bq, d) = dO tile
+        g = g_ref[0]                                   # (bq, dv) = dO tile
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (bq, bkv)
@@ -240,7 +253,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dk_ref,
     def _block():
         q = q_ref[0]                                   # (bq, d)
         k = k_ref[0]                                   # (bkv, d)
-        g = g_ref[0]                                   # (bq, d) = dO tile
+        g = g_ref[0]                                   # (bq, dv) = dO tile
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (bq, bkv)
@@ -252,7 +265,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dk_ref,
         p = jnp.where(valid, jnp.exp(s - lse_ref[0, 0][:, None]), 0.0)
         dv_acc[...] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (bkv, d)
+            preferred_element_type=jnp.float32)          # (bkv, dv)
         dp = jax.lax.dot_general(
             g, v_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)          # (bq, bkv)
@@ -268,14 +281,16 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dk_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bkv",
-                                             "interpret", "kv_len"))
+                                             "interpret", "kv_len", "scale"))
 def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         o: jnp.ndarray, lse: jnp.ndarray, g: jnp.ndarray, *,
                         causal: bool = True, bq: int = 256, bkv: int = 256,
-                        interpret: bool = True, kv_len: int = 0):
-    """Blockwise dq/dk/dv. q, k, v as in ``flash_attention``; o/lse are the
-    stashed forward output + per-row log-sum-exp (BH, T); g is the output
-    cotangent (BH, T, d). Returns (dq, dk, dv) in the input dtypes. Both
+                        interpret: bool = True, kv_len: int = 0,
+                        scale: float | None = None):
+    """Blockwise dq/dk/dv. q, k, v and ``scale`` as in ``flash_attention``
+    (v, o and g carry the v head dim dv); o/lse are the stashed forward
+    output + per-row log-sum-exp (BH, T); g is the output cotangent
+    (BH, T, dv). Returns (dq, dk, dv) in the input dtypes. Both
     row statistics (lse and D) reach the kernels as (BH, 1, T) lane rows
     in (1, 1, bq) blocks, the layout the TPU's block rule admits.
 
@@ -285,23 +300,25 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     come out exactly zero.
     """
     bh, t, d = q.shape
-    s_len = k.shape[1]
+    s_len, dv = k.shape[1], v.shape[2]
     assert t % bq == 0 and s_len % bkv == 0, (t, s_len, bq, bkv)
     kv_len = kv_len or s_len
     # per-row correction D = rowsum(dO ⊙ O): O(T·d), stays out of kernels
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None]
     lse = lse.astype(jnp.float32)[:, None]
-    common = dict(scale=d ** -0.5, causal=causal, bq=bq, bkv=bkv,
+    common = dict(scale=_scale(d, scale), causal=causal, bq=bq, bkv=bkv,
                   kv_len=kv_len)
     qspec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, bkv, d), lambda b, i, j: (b, j, 0))
+    vspec = pl.BlockSpec((1, bkv, dv), lambda b, i, j: (b, j, 0))
+    gspec = pl.BlockSpec((1, bq, dv), lambda b, i, j: (b, i, 0))
     rowspec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, kv_steps=s_len // bkv, **common),
         name="flash_attention_bwd_dq",
         grid=(bh, t // bq, s_len // bkv),
-        in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
+        in_specs=[qspec, kspec, vspec, gspec, rowspec, rowspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
@@ -312,22 +329,24 @@ def flash_attention_bwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     # dk/dv: kv tiles on the parallel dim, q tiles innermost (sequential)
     qspec2 = pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0))
     kspec2 = pl.BlockSpec((1, bkv, d), lambda b, j, i: (b, j, 0))
+    vspec2 = pl.BlockSpec((1, bkv, dv), lambda b, j, i: (b, j, 0))
+    gspec2 = pl.BlockSpec((1, bq, dv), lambda b, j, i: (b, i, 0))
     rowspec2 = pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, i))
-    dk, dv = pl.pallas_call(
+    dk, dv_out = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, q_steps=t // bq, **common),
         name="flash_attention_bwd_dkv",
         grid=(bh, s_len // bkv, t // bq),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rowspec2, rowspec2],
-        out_specs=[kspec2, kspec2],
+        in_specs=[qspec2, kspec2, vspec2, gspec2, rowspec2, rowspec2],
+        out_specs=[kspec2, vspec2],
         out_shape=[jax.ShapeDtypeStruct((bh, s_len, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, s_len, d), v.dtype)],
+                   jax.ShapeDtypeStruct((bh, s_len, dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bkv, d), jnp.float32),
-                        pltpu.VMEM((bkv, d), jnp.float32)],
+                        pltpu.VMEM((bkv, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, g, lse, delta)
-    return dq, dk, dv
+    return dq, dk, dv_out
 
 
 # ---------------------------------------------------------------------------

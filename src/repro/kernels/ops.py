@@ -22,6 +22,7 @@ from repro.kernels.flash_attention import decode_attention as _decode_attn
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.flash_attention import flash_attention_bwd as _flash_bwd
 from repro.kernels.flash_attention import flash_attention_fwd as _flash_fwd
+from repro.kernels.grouped_matmul import grouped_matmul as _gmm
 from repro.kernels.paged_attention import (
     paged_decode_attention as _paged_attn, step_shape as _paged_step_shape)
 from repro.kernels.tt_linear import tt_linear as _tt_linear
@@ -216,8 +217,9 @@ def tt_linear_batched_a(x, w, a, b, *, alpha: float = 1.0,
 
 def flash_attention(q, k, v, *, causal: bool = True, backend: str = "auto",
                     interpret: bool | None = None, bq: int = 0,
-                    bkv: int = 0):
-    """GQA flash attention. q: (B, T, H, d); k, v: (B, S, KV, d).
+                    bkv: int = 0, scale: float | None = None):
+    """GQA flash attention. q: (B, T, H, d); k: (B, S, KV, d); v: (B, S,
+    KV, dv) -> (B, T, H, dv). ``scale`` multiplies q·kᵀ (None -> d^-0.5).
 
     KV heads are broadcast to the query-head count before the per-head
     kernel call (zero-copy under XLA when G == 1). T and S need not be tile
@@ -230,33 +232,36 @@ def flash_attention(q, k, v, *, causal: bool = True, backend: str = "auto",
         vv = jnp.repeat(v, g, axis=2) if g > 1 else v
         out = _ref.flash_attention_ref(
             q.transpose(0, 2, 1, 3), kk.transpose(0, 2, 1, 3),
-            vv.transpose(0, 2, 1, 3), causal=causal)
+            vv.transpose(0, 2, 1, 3), causal=causal, scale=scale)
         return out.transpose(0, 2, 1, 3)
     b, t, h, d = q.shape
-    s, kv = k.shape[1], k.shape[2]
+    s, kv, dv = k.shape[1], k.shape[2], v.shape[3]
     g = h // kv
     qh = q.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     kh = jnp.repeat(k.transpose(0, 2, 1, 3), g, axis=1).reshape(b * h, s, d)
-    vh = jnp.repeat(v.transpose(0, 2, 1, 3), g, axis=1).reshape(b * h, s, d)
+    vh = jnp.repeat(v.transpose(0, 2, 1, 3), g, axis=1).reshape(b * h, s,
+                                                                 dv)
     bq = _pick_tile(t, bq, (256, 128))
     bkv = _pick_tile(s, bkv, (256, 128))
     qh, t0 = _pad_to(qh, 1, bq)
     kh, s0 = _pad_to(kh, 1, bkv)
     vh, _ = _pad_to(vh, 1, bkv)
     out = _flash(qh, kh, vh, causal=causal, bq=bq, bkv=bkv,
-                 interpret=_interp(interpret), kv_len=s0)
-    return out[:, :t0].reshape(b, h, t0, d).transpose(0, 2, 1, 3)
+                 interpret=_interp(interpret), kv_len=s0, scale=scale)
+    return out[:, :t0].reshape(b, h, t0, dv).transpose(0, 2, 1, 3)
 
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True,
                         backend: str = "auto", interpret: bool | None = None,
-                        bq: int = 0, bkv: int = 0):
+                        bq: int = 0, bkv: int = 0,
+                        scale: float | None = None):
     """Stats-emitting GQA flash forward for training.
 
-    Same layout contract as ``flash_attention`` — q: (B, T, H, d); k, v:
-    (B, S, KV, d) — but also returns the per-row log-sum-exp residual
-    ``lse`` with shape (B, H, T) f32, which ``flash_attention_bwd`` needs
-    to rebuild probability tiles without ever materializing (T, S).
+    Same layout contract as ``flash_attention`` — q: (B, T, H, d); k:
+    (B, S, KV, d); v: (B, S, KV, dv) — but also returns the per-row
+    log-sum-exp residual ``lse`` with shape (B, H, T) f32, which
+    ``flash_attention_bwd`` needs to rebuild probability tiles without
+    ever materializing (T, S).
     """
     if _use_ref(backend):
         g = q.shape[2] // k.shape[2]
@@ -265,9 +270,9 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
         qh = q.transpose(0, 2, 1, 3)
         kh = kk.transpose(0, 2, 1, 3)
         vh = vv.transpose(0, 2, 1, 3)
-        scale = q.shape[-1] ** -0.5
+        sc = q.shape[-1] ** -0.5 if scale is None else scale
         s = jnp.einsum("bhqd,bhkd->bhqk", qh, kh,
-                       preferred_element_type=jnp.float32) * scale
+                       preferred_element_type=jnp.float32) * sc
         if causal:
             t, s_len = q.shape[1], k.shape[1]
             mask = jnp.arange(t)[:, None] >= jnp.arange(s_len)[None, :]
@@ -277,19 +282,21 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
         out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(vh.dtype), vh)
         return out.transpose(0, 2, 1, 3).astype(q.dtype), lse
     b, t, h, d = q.shape
-    s, kv = k.shape[1], k.shape[2]
+    s, kv, dv = k.shape[1], k.shape[2], v.shape[3]
     g = h // kv
     qh = q.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     kh = jnp.repeat(k.transpose(0, 2, 1, 3), g, axis=1).reshape(b * h, s, d)
-    vh = jnp.repeat(v.transpose(0, 2, 1, 3), g, axis=1).reshape(b * h, s, d)
+    vh = jnp.repeat(v.transpose(0, 2, 1, 3), g, axis=1).reshape(b * h, s,
+                                                                 dv)
     bq = _pick_tile(t, bq, (256, 128))
     bkv = _pick_tile(s, bkv, (256, 128))
     qh, t0 = _pad_to(qh, 1, bq)
     kh, s0 = _pad_to(kh, 1, bkv)
     vh, _ = _pad_to(vh, 1, bkv)
     out, lse = _flash_fwd(qh, kh, vh, causal=causal, bq=bq, bkv=bkv,
-                          interpret=_interp(interpret), kv_len=s0)
-    out = out[:, :t0].reshape(b, h, t0, d).transpose(0, 2, 1, 3)
+                          interpret=_interp(interpret), kv_len=s0,
+                          scale=scale)
+    out = out[:, :t0].reshape(b, h, t0, dv).transpose(0, 2, 1, 3)
     return out, lse[:, 0, :t0].reshape(b, h, t0)
 
 
@@ -303,18 +310,20 @@ def _group_sum_kv(dx, b: int, kv: int, grp: int, s: int, d: int, dtype):
 
 def flash_attention_bwd(q, k, v, o, lse, g, *, causal: bool = True,
                         backend: str = "auto", interpret: bool | None = None,
-                        bq: int = 0, bkv: int = 0):
+                        bq: int = 0, bkv: int = 0,
+                        scale: float | None = None):
     """Blockwise GQA flash backward: (dq, dk, dv) from stashed residuals.
 
-    q, o, g: (B, T, H, d); k, v: (B, S, KV, d); lse: (B, H, T) f32 from
-    ``flash_attention_fwd``. dk/dv come back in KV-head layout — the GQA
+    q: (B, T, H, d); o, g: (B, T, H, dv); k: (B, S, KV, d); v: (B, S, KV,
+    dv); lse: (B, H, T) f32 from ``flash_attention_fwd`` under the same
+    ``scale``. dk/dv come back in KV-head layout — the GQA
     broadcast's adjoint sums each group of query heads in f32. Padded
     query rows carry a +1e30 lse sentinel so their recomputed probability
     tiles are exactly zero (no inf·0 NaNs); padded keys are masked by
     ``kv_len`` inside the kernels.
     """
     b, t, h, d = q.shape
-    s, kv = k.shape[1], k.shape[2]
+    s, kv, dvh = k.shape[1], k.shape[2], v.shape[3]
     grp = h // kv
     if _use_ref(backend):
         kk = jnp.repeat(k, grp, axis=2) if grp > 1 else k
@@ -322,18 +331,19 @@ def flash_attention_bwd(q, k, v, o, lse, g, *, causal: bool = True,
         dq, dk, dv = _ref.flash_attention_bwd_ref(
             q.transpose(0, 2, 1, 3), kk.transpose(0, 2, 1, 3),
             vv.transpose(0, 2, 1, 3), o.transpose(0, 2, 1, 3), lse,
-            g.transpose(0, 2, 1, 3), causal=causal)
+            g.transpose(0, 2, 1, 3), causal=causal, scale=scale)
         dq = dq.transpose(0, 2, 1, 3)
         dk = _group_sum_kv(dk.reshape(b * h, s, d), b, kv, grp, s, d,
                            k.dtype)
-        dv = _group_sum_kv(dv.reshape(b * h, s, d), b, kv, grp, s, d,
+        dv = _group_sum_kv(dv.reshape(b * h, s, dvh), b, kv, grp, s, dvh,
                            v.dtype)
         return dq, dk, dv
     qh = q.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     kh = jnp.repeat(k.transpose(0, 2, 1, 3), grp, axis=1).reshape(b * h, s, d)
-    vh = jnp.repeat(v.transpose(0, 2, 1, 3), grp, axis=1).reshape(b * h, s, d)
-    oh = o.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-    gh = g.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+    vh = jnp.repeat(v.transpose(0, 2, 1, 3), grp, axis=1).reshape(b * h, s,
+                                                                   dvh)
+    oh = o.transpose(0, 2, 1, 3).reshape(b * h, t, dvh)
+    gh = g.transpose(0, 2, 1, 3).reshape(b * h, t, dvh)
     lseh = lse.reshape(b * h, t)
     bq = _pick_tile(t, bq, (256, 128))
     bkv = _pick_tile(s, bkv, (256, 128))
@@ -348,10 +358,10 @@ def flash_attention_bwd(q, k, v, o, lse, g, *, causal: bool = True,
     vh, _ = _pad_to(vh, 1, bkv)
     dq, dk, dv = _flash_bwd(qh, kh, vh, oh, lseh, gh, causal=causal,
                             bq=bq, bkv=bkv, interpret=_interp(interpret),
-                            kv_len=s0)
+                            kv_len=s0, scale=scale)
     dq = dq[:, :t0].reshape(b, h, t0, d).transpose(0, 2, 1, 3)
     dk = _group_sum_kv(dk[:, :s0], b, kv, grp, s0, d, k.dtype)
-    dv = _group_sum_kv(dv[:, :s0], b, kv, grp, s0, d, v.dtype)
+    dv = _group_sum_kv(dv[:, :s0], b, kv, grp, s0, dvh, v.dtype)
     return dq, dk, dv
 
 
@@ -429,3 +439,27 @@ def paged_decode_attention(q, k_cache, v_cache, tables, pos, *,
                       pages_per_step=pps, heads=heads,
                       interpret=_interp(interpret))
     return out.transpose(0, 3, 1, 2, 4).reshape(b, c, h, d)
+
+
+def grouped_matmul(x, w, tile_group, n_used, *, tm: int,
+                   transpose_w: bool = False, backend: str = "auto",
+                   interpret: bool | None = None):
+    """Expert-grouped matmul of sorted rows (kernels/grouped_matmul.py).
+
+    x: (R, K), R a multiple of ``tm``, row tile i belonging to expert
+    ``tile_group[i]``; w: (E, K, N) (``transpose_w``: (E, N, K));
+    n_used: (1,) int32, the tiles that hold rows. K and N pad up to a tile
+    multiple (zeros, exact) and the output slices back to (R, N)."""
+    if _use_ref(backend):
+        return _ref.grouped_matmul_ref(x, w, tile_group, n_used, tm,
+                                       transpose_w)
+    k_dim = x.shape[1]
+    n_dim = w.shape[1] if transpose_w else w.shape[2]
+    tk = _pick_tile(k_dim, 0, (512, 256, 128))
+    tn = _pick_tile(n_dim, 0, (512, 256, 128))
+    x, _ = _pad_to(x, 1, tk)
+    w, _ = _pad_to(w, 2 if transpose_w else 1, tk)
+    w, _ = _pad_to(w, 1 if transpose_w else 2, tn)
+    y = _gmm(x, w, tile_group, n_used, tm=tm, tk=tk, tn=tn,
+             transpose_w=transpose_w, interpret=_interp(interpret))
+    return y[:, :n_dim]

@@ -43,9 +43,11 @@ def tt_linear_batched_a_q_ref(x: jnp.ndarray, wq: jnp.ndarray,
 
 
 def flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
-                        causal: bool = True) -> jnp.ndarray:
-    """q,k,v: (B, H, T, d) -> (B, H, T, d), softmax in f32."""
-    scale = q.shape[-1] ** -0.5
+                        causal: bool = True,
+                        scale: float | None = None) -> jnp.ndarray:
+    """q, k: (B, H, T|S, d); v: (B, H, S, dv) -> (B, H, T, dv), softmax in
+    f32; ``scale`` multiplies q·kᵀ (None -> d^-0.5)."""
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
@@ -58,17 +60,19 @@ def flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 def flash_attention_bwd_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                             o: jnp.ndarray, lse: jnp.ndarray,
-                            g: jnp.ndarray, causal: bool = True):
+                            g: jnp.ndarray, causal: bool = True,
+                            scale: float | None = None):
     """Recompute-from-lse twin of the blockwise flash backward.
 
-    q, o, g: (B, H, T, d); k, v: (B, H, S, d); lse: (B, H, T) f32 per-row
+    q: (B, H, T, d); o, g: (B, H, T, dv); k: (B, H, S, d); v: (B, H, S,
+    dv); lse: (B, H, T) f32 per-row
     log-sum-exp stashed by the forward. Returns (dq, dk, dv) via the same
     math the Pallas kernels run — p = exp(s − lse), ds = p·(dp − D)·scale
     with D = rowsum(g ⊙ o) — including the dtype casts of p/ds back to the
     operand dtype before each contraction, so bf16 parity with the kernel
     is exact rather than merely close.
     """
-    scale = q.shape[-1] ** -0.5
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
@@ -136,3 +140,21 @@ def paged_decode_attention_ref(q: jnp.ndarray, k_cache: jnp.ndarray,
     s = jnp.where(mask[:, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhcs,bshd->bchd", p.astype(vg.dtype), vg)
+
+
+def grouped_matmul_ref(x: jnp.ndarray, w: jnp.ndarray,
+                       tile_group: jnp.ndarray, n_used: jnp.ndarray,
+                       tm: int, transpose_w: bool = False) -> jnp.ndarray:
+    """Twin of kernels/grouped_matmul.py: row tile i of x (R, K) times
+    expert ``tile_group[i]``'s w (E, K, N) (or its transpose, w (E, N, K)),
+    zero in tiles at or past ``n_used``; one masked matmul per expert."""
+    r = x.shape[0]
+    tile = jnp.arange(r) // tm
+    group = jnp.where(tile < n_used[0], tile_group[tile], -1)
+    out = None
+    for e in range(w.shape[0]):
+        we = w[e].T if transpose_w else w[e]
+        y = jnp.dot(jnp.where((group == e)[:, None], x, 0), we.astype(x.dtype),
+                    preferred_element_type=jnp.float32)
+        out = y if out is None else out + y
+    return out.astype(x.dtype)

@@ -1,5 +1,6 @@
 """GQA/MQA attention with RoPE, KV cache, cross-attention and a
-flash-style chunked path for long sequences.
+flash-style chunked path for long sequences; multi-head latent attention
+(``mla_attention``, DeepSeek-V3 / Kimi K2) for training and prefill.
 
 Three execution paths:
   * naive      — full score matrix; smoke tests / short sequences.
@@ -18,16 +19,17 @@ types "<prefix>_q" etc., so MetaTT's M axis addresses them (paper §2.2).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.config.base import ModelConfig
+from repro.config.base import ModelConfig, YaRN
 from repro.kernels import dispatch
 from repro.kernels import quant as quant_lib
 from repro.models.layers import (AdapterCtx, adapted_linear, apply_rope,
-                                 serve_rp_linear)
+                                 rmsnorm, serve_rp_linear)
 from repro.sharding import (BATCH, SEQ, current_mesh, get_serve_rp,
                             maybe_shard, serve_tp_gather, serve_tp_slice)
 
@@ -94,8 +96,8 @@ def _chunked_attend(q, k, v, scale, causal, chunk):
 
     qs = q.reshape(b, n, chunk, kv, g, hd).transpose(1, 0, 2, 3, 4, 5)
     offs = jnp.arange(n) * chunk
-    out = jax.lax.map(one, (qs, offs))           # (n, B, chunk, KV, G, hd)
-    return out.transpose(1, 0, 2, 3, 4, 5).reshape(b, t, kv, g, hd)
+    out = jax.lax.map(one, (qs, offs))           # (n, B, chunk, KV, G, dv)
+    return out.transpose(1, 0, 2, 3, 4, 5).reshape(b, t, kv, g, -1)
 
 
 def attention(x: jnp.ndarray, w: dict, ctx: AdapterCtx, cfg: ModelConfig, *,
@@ -388,3 +390,123 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, page_size: int,
                 "k_s": jnp.zeros(s_shape, jnp.float32),
                 "v_s": jnp.zeros(s_shape, jnp.float32)}
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V3 / Kimi K2 block), train/prefill
+# ---------------------------------------------------------------------------
+
+
+def yarn_mscale(s: float, m: float) -> float:
+    """YaRN's attention temperature: ``mscale(s, m) = 0.1·m·ln s + 1``
+    (1 for s <= 1)."""
+    return 1.0 if s <= 1 else 0.1 * m * math.log(s) + 1.0
+
+
+def yarn_inv_freq(d: int, base: float, y: Optional[YaRN]) -> jnp.ndarray:
+    """Rotary frequencies of a ``d``-wide rope part, (d/2,) f32.
+
+    Plain rope: ``base^(-2i/d)``. With YaRN (factor s, original length
+    ``orig``):
+        freq_extra[i] = base^(-2i/d),   freq_inter = freq_extra / s
+        corr(b) = d·ln(orig / (2π·b)) / (2·ln base)
+        low = floor(corr(beta_fast)), high = ceil(corr(beta_slow))
+              (clipped to [0, d-1]; high += 0.001 if equal)
+        ramp[i] = clip((i - low) / (high - low), 0, 1)
+        inv_freq = freq_inter·ramp + freq_extra·(1 - ramp)
+    Kimi K2 (d 64, base 50000, s 32, orig 4096, beta 1/1): low 19, high
+    20."""
+    i = jnp.arange(d // 2, dtype=jnp.float32)
+    extra = base ** (-2.0 * i / d)
+    if y is None:
+        return extra
+
+    def corr(b):
+        return d * math.log(y.original_max_position / (2 * math.pi * b)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(corr(y.beta_fast)), 0)
+    high = min(math.ceil(corr(y.beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((i - low) / (high - low), 0.0, 1.0)
+    return (extra / y.factor) * ramp + extra * (1.0 - ramp)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``qk_head_dim^-0.5``, times ``mscale(s, mscale_all_dim)²`` under
+    YaRN (Kimi K2: 192^-0.5 · 1.8133)."""
+    y = cfg.rope_scaling
+    sc = cfg.qk_head_dim ** -0.5
+    if y is not None and y.mscale_all_dim:
+        sc *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return sc
+
+
+def mla_rope(x: jnp.ndarray, positions: jnp.ndarray,
+             cfg: ModelConfig) -> jnp.ndarray:
+    """Rotate x (B, T, n, d_rope) at ``positions`` ((T,) or (B, T)) with
+    YaRN's frequencies; cos and sin carry YaRN's factor
+    ``mscale(s, mscale) / mscale(s, mscale_all_dim)`` (1 for Kimi K2).
+    Layout: half-split pairs (column i with column i + d/2), the layout the
+    rest of the program uses; the published checkpoint interleaves its
+    pairs, a fixed permutation of the rope columns of q_b and kv_a."""
+    y = cfg.rope_scaling
+    amp = 1.0 if y is None else (yarn_mscale(y.factor, y.mscale)
+                                 / yarn_mscale(y.factor, y.mscale_all_dim))
+    return apply_rope(x, positions, cfg.rope_theta,
+                      freqs=yarn_inv_freq(x.shape[-1], cfg.rope_theta, y),
+                      amp=amp)
+
+
+def mla_attention(x: jnp.ndarray, w: dict, ctx: AdapterCtx,
+                  cfg: ModelConfig, *, positions=None, chunk: int = 0):
+    """Multi-head latent attention over a whole causal sequence.
+    x: (B, T, d_model) -> (B, T, d_model).
+
+        c_q = q_a(x) ;  q = q_b(RMSNorm(c_q))          (B, T, H, 128 + 64)
+        [c, k_pe] = kv_a(x)                  c: 512 wide, k_pe: 64, one head
+        [k_nope, v] = kv_b(RMSNorm(c))                 (B, T, H, 128 + 128)
+        q = [q_nope, rope(q_pe)] ;  k = [k_nope, rope(k_pe)]
+                                      (rope(k_pe) broadcast over the heads)
+        o = softmax(q·kᵀ · scale, causal) · v ;  y = o_proj(o)
+
+    Both RMSNorms use ``norm_eps`` and no bias; ``scale`` is
+    ``mla_softmax_scale``; rope is ``mla_rope``. The q_a, q_b, kv_a, kv_b
+    and o matrices are the adaptable types ``mla_q_a``, ``mla_q_b``,
+    ``mla_kv_a``, ``mla_kv_b`` and ``mla_o``. On the kernel path the
+    attention runs in the flash kernels with q/k head dim 192 and v head
+    dim 128; elsewhere in plain XLA."""
+    b, t, _ = x.shape
+    h, dn = cfg.num_heads, cfg.qk_nope_head_dim
+    dr, dv, r = cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    if positions is None:
+        positions = jnp.arange(t)
+    with jax.named_scope("mla"):
+        cq = adapted_linear(x, w["wq_a"], ctx, "mla_q_a")
+        q = adapted_linear(rmsnorm(cq, w["q_norm"], cfg.norm_eps),
+                           w["wq_b"], ctx, "mla_q_b").reshape(b, t, h,
+                                                              dn + dr)
+        ckv = adapted_linear(x, w["wkv_a"], ctx, "mla_kv_a")
+        c, k_pe = ckv[..., :r], ckv[..., r:]
+        kv = adapted_linear(rmsnorm(c, w["kv_norm"], cfg.norm_eps),
+                            w["wkv_b"], ctx, "mla_kv_b").reshape(b, t, h,
+                                                                 dn + dv)
+        k_nope, v = kv[..., :dn], kv[..., dn:]
+        q_pe = mla_rope(q[..., dn:], positions, cfg)
+        k_pe = mla_rope(k_pe[:, :, None, :], positions, cfg)
+        q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe, (b, t, h, dr))], axis=-1)
+        scale = mla_softmax_scale(cfg)
+        if _flash_ok(ctx):
+            out = dispatch.flash_attention(q, k, v, causal=True,
+                                           policy=ctx.policy, scale=scale)
+        elif chunk and t % chunk == 0 and t > chunk:
+            out = _chunked_attend(q[:, :, :, None], k, v, scale, True,
+                                  chunk)
+        else:
+            out = _softmax_attend(q[:, :, :, None], k, v,
+                                  _causal_mask(t, t), scale)
+        y = adapted_linear(out.reshape(b, t, h * dv), w["wo"], ctx, "mla_o")
+    return maybe_shard(y, BATCH, SEQ, None)

@@ -224,15 +224,21 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
                             / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float) -> jnp.ndarray:
-    """x: (B, T, n_heads, head_dim); positions: (B, T) or (T,)."""
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               freqs: Optional[jnp.ndarray] = None,
+               amp: float = 1.0) -> jnp.ndarray:
+    """x: (B, T, n_heads, head_dim); positions: (B, T) or (T,). ``freqs``
+    (hd/2,) replaces the plain ``theta`` frequencies, and ``amp`` scales
+    cos and sin (YaRN, models/attention.py::mla_rope)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                       # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                   # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B?, T, hd/2)
     if ang.ndim == 2:
         ang = ang[None]
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    if amp != 1.0:
+        cos, sin = cos * amp, sin * amp
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.config.base import ModelConfig, RunConfig
 from repro.core.metatt import MetaTTConfig
+from repro.models import moe as moe_lib
 from repro.models import transformer
 from repro.peft import api as peft_api
 from repro.peft.lora import LoRAConfig
@@ -30,6 +31,13 @@ def matrix_dims(cfg: ModelConfig) -> dict:
     if "attn" in mixers or cfg.is_encdec:
         out.update({"attn_q": (d, q), "attn_k": (d, kv), "attn_v": (d, kv),
                     "attn_o": (q, d)})
+    if "mla" in mixers:
+        h, rq, rkv = cfg.num_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+        out.update({
+            "mla_q_a": (d, rq), "mla_q_b": (rq, h * cfg.qk_head_dim),
+            "mla_kv_a": (d, rkv + cfg.qk_rope_head_dim),
+            "mla_kv_b": (rkv, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            "mla_o": (h * cfg.v_head_dim, d)})
     if cfg.is_encdec:
         out.update({"xattn_q": (d, q), "xattn_k": (d, kv),
                     "xattn_v": (d, kv), "xattn_o": (q, d)})
@@ -55,6 +63,9 @@ def default_matrices(cfg: ModelConfig, variant: str = "4d") -> tuple:
     out = []
     if "attn" in mixers or cfg.is_encdec:
         out += ["attn_q", "attn_v"]
+    if "mla" in mixers:
+        # MLA's up-projections to the per-head queries and keys/values
+        out += ["mla_q_b", "mla_kv_b"]
     if cfg.is_encdec:
         out += ["xattn_q", "xattn_v"]
     if "mamba" in mixers:
@@ -187,6 +198,7 @@ def loss_fn(adapter, base, frozen, batch: dict, cfg: ModelConfig,
     loss = next_token_loss(out.logits, batch["tokens"], batch.get("mask"),
                            prefix, vocab_size=cfg.vocab_size)
     aux_weight = cfg.moe_aux_weight if aux_weight is None else aux_weight
-    aux_total = sum(out.aux.values()) if out.aux else 0.0
+    aux_total = sum(v for k, v in out.aux.items()
+                    if k not in moe_lib.COUNTERS and k not in moe_lib.RECORDS)
     metrics = {"ce": loss, **out.aux}
     return loss + aux_weight * aux_total, metrics

@@ -121,11 +121,29 @@ def _ffn_init(cfg: ModelConfig, key, nb, dtype):
     return w
 
 
+def _mla_init(cfg: ModelConfig, key, nb, dtype):
+    d, h = cfg.d_model, cfg.num_heads
+    rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    ks = jax.random.split(key, 5)
+    return {
+        "wq_a": _linear_init(ks[0], d, rq, nb, dtype),
+        "q_norm": jnp.zeros((nb, rq), jnp.float32),
+        "wq_b": _linear_init(ks[1], rq, h * cfg.qk_head_dim, nb, dtype),
+        "wkv_a": _linear_init(ks[2], d, rkv + cfg.qk_rope_head_dim, nb,
+                              dtype),
+        "kv_norm": jnp.zeros((nb, rkv), jnp.float32),
+        "wkv_b": _linear_init(ks[3], rkv,
+                              h * (cfg.qk_nope_head_dim + cfg.v_head_dim),
+                              nb, dtype),
+        "wo": _linear_init(ks[4], h * cfg.v_head_dim, d, nb, dtype),
+    }
+
+
 def _moe_init(cfg: ModelConfig, key, nb, dtype):
-    d, ff, e = cfg.d_model, cfg.d_ff, cfg.num_experts
+    d, ff, e = cfg.d_model, cfg.d_ff, cfg.local_experts
     ks = jax.random.split(key, 7)
     w = {
-        "router": _linear_init(ks[0], d, e, nb, jnp.float32),
+        "router": _linear_init(ks[0], d, cfg.num_experts, nb, jnp.float32),
         "e_wg": (jax.random.normal(ks[1], (nb, e, d, ff), jnp.float32)
                  / jnp.sqrt(d)).astype(dtype),
         "e_wu": (jax.random.normal(ks[2], (nb, e, d, ff), jnp.float32)
@@ -133,6 +151,8 @@ def _moe_init(cfg: ModelConfig, key, nb, dtype):
         "e_wd": (jax.random.normal(ks[3], (nb, e, ff, d), jnp.float32)
                  / jnp.sqrt(ff)).astype(dtype),
     }
+    if cfg.moe_scoring == "sigmoid":
+        w["router_bias"] = jnp.zeros((nb, cfg.num_experts), jnp.float32)
     if cfg.num_shared_experts:
         sff = ff * cfg.num_shared_experts
         w["s_wg"] = _linear_init(ks[4], d, sff, nb, dtype)
@@ -141,8 +161,27 @@ def _moe_init(cfg: ModelConfig, key, nb, dtype):
     return w
 
 
-_MIXER_INIT = {"attn": _attn_init, "mamba": _mamba_init,
+_MIXER_INIT = {"attn": _attn_init, "mla": _mla_init, "mamba": _mamba_init,
                "mlstm": _mlstm_init, "slstm": _slstm_init}
+
+
+def dense_pattern(cfg: ModelConfig) -> tuple:
+    """The leading dense layers' pattern: the first mixer, a dense FFN."""
+    return ((cfg.block_pattern[0][0], "dense"),)
+
+
+def _dense_cfg(cfg: ModelConfig) -> ModelConfig:
+    """``cfg`` as its leading dense layers see it (dense width d_ff)."""
+    return dataclasses.replace(cfg, block_pattern=dense_pattern(cfg),
+                               d_ff=cfg.dense_d_ff,
+                               num_layers=cfg.first_dense_layers,
+                               first_dense_layers=0)
+
+
+def head_weights(base: dict, cfg: ModelConfig):
+    """The readout's (V, d) table: the embedding when tied, else the
+    separate ``lm_head``."""
+    return base["embed"]["tok"] if cfg.tie_embeddings else base["lm_head"]
 
 
 def _block_init(cfg: ModelConfig, key, nb, *, decoder_cross: bool, dtype):
@@ -176,6 +215,14 @@ def init_base_params(cfg: ModelConfig, key) -> dict:
                               decoder_cross=cfg.is_encdec, dtype=dtype),
         "final_norm": _norm_init(cfg, 1),
     }
+    if cfg.first_dense_layers:
+        params["dense_blocks"] = _block_init(
+            _dense_cfg(cfg), jax.random.fold_in(key, 1),
+            cfg.first_dense_layers, decoder_cross=False, dtype=dtype)
+    if not cfg.tie_embeddings:
+        params["lm_head"] = (jax.random.normal(
+            jax.random.fold_in(key, 2), (cfg.padded_vocab, cfg.d_model),
+            jnp.float32) * 0.02).astype(dtype)
     if cfg.is_encdec:
         enc_cfg = dataclasses.replace(cfg, block_pattern=(("attn", "dense"),),
                                       num_layers=cfg.encoder_layers)
@@ -217,6 +264,13 @@ def _sublayer(h, blk, mixer, ffn, ctx: AdapterCtx, cfg: ModelConfig, *,
                 block_tables=block_tables)
             if c is not None:
                 new_cache["self"] = c
+        elif mixer == "mla":
+            if cache is not None:
+                raise NotImplementedError(
+                    "latent attention has no KV cache (training and "
+                    "prefill only)")
+            y = attn_lib.mla_attention(hn, blk["mixer"], ctx, cfg,
+                                       positions=positions, chunk=chunk)
         elif mixer == "mamba":
             y, c = mamba_lib.mamba_mixer(hn, blk["mixer"], ctx, cfg,
                                          cache=(cache or {}).get("ssm"))
@@ -287,7 +341,8 @@ def run_blocks(h, blocks, pattern, spec: peft_api.AdapterSpec, broadcast,
                 block_tables=block_tables)
             new_cch.append(nc)
             for k, v in aux.items():
-                aux_acc[k] = aux_acc.get(k, 0.0) + v
+                aux_acc[k] = (_aux_reduce(k)(aux_acc[k], v) if k in aux_acc
+                              else v)
         return h, (new_cch, aux_acc)
 
     if remat:
@@ -295,8 +350,38 @@ def run_blocks(h, blocks, pattern, spec: peft_api.AdapterSpec, broadcast,
 
     xs = (blocks, pl, caches if has_cache else [{} for _ in range(p)])
     h, (new_caches, aux_stack) = jax.lax.scan(body, h, xs, length=nb)
-    aux = {k: jnp.sum(v) for k, v in aux_stack.items()}
+    aux = {k: _aux_over_blocks(k, v) for k, v in aux_stack.items()}
     return h, new_caches, aux
+
+
+def _aux_over_blocks(name: str, v):
+    """An aux value stacked over the scan's blocks, combined: the records
+    (models/moe.py::RECORDS) keep one row per layer, the counters follow
+    their own rule (models/moe.py::COUNTERS), the rest are summed."""
+    if name in moe_lib.RECORDS:
+        return v.reshape((-1,) + v.shape[2:])
+    return jnp.max(v) if _aux_reduce(name) is jnp.maximum else jnp.sum(v)
+
+
+def _concat(a, b):
+    return jnp.concatenate([a, b])
+
+
+def _aux_reduce(name: str):
+    """How an aux value combines across layers: records concatenate, the
+    counters follow their own rule (models/moe.py::COUNTERS), the rest
+    add."""
+    if name in moe_lib.RECORDS:
+        return _concat
+    return moe_lib.COUNTERS.get(name, jnp.add)
+
+
+def merge_aux(a: dict, b: dict) -> dict:
+    """Two runs of layers' aux values, combined key by key."""
+    out = dict(a)
+    for k, v in b.items():
+        out[k] = _aux_reduce(k)(out[k], v) if k in out else v
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -351,15 +436,24 @@ def forward(base, cfg: ModelConfig, spec, broadcast, per_layer, tokens=None,
     t = h.shape[1]
     positions = jnp.arange(t)
 
+    if cfg.first_dense_layers:
+        # the leading dense layers, then the scanned pattern after them
+        h, _, aux_d = run_blocks(
+            h, base["dense_blocks"], dense_pattern(cfg), spec, broadcast,
+            per_layer, cfg, causal=True, positions=positions,
+            layer_offset=layer_offset, task=task, remat=remat, chunk=chunk,
+            nb=cfg.first_dense_layers, policy=policy)
+        aux = merge_aux(aux, aux_d)
+        layer_offset += cfg.first_dense_layers
     h, new_caches, aux2 = run_blocks(
         h, base["blocks"], cfg.block_pattern, spec, broadcast, per_layer,
         cfg, causal=True, positions=positions, enc_out=enc_out,
         layer_offset=layer_offset, task=task, remat=remat, chunk=chunk,
         caches=None, policy=policy)
-    aux.update(aux2)
+    aux = merge_aux(aux, aux2)
     h = norm(h, jax.tree_util.tree_map(lambda a: a[0], base["final_norm"]),
              cfg.norm_eps)
-    logits = lm_logits(h, base["embed"]["tok"])
+    logits = lm_logits(h, head_weights(base, cfg))
     return ModelOutputs(logits=logits, aux=aux, caches=new_caches,
                         enc_out=enc_out)
 
@@ -498,9 +592,9 @@ def paged_step(base, cfg: ModelConfig, spec, broadcast, per_layer, toks,
     h = norm(h, jax.tree_util.tree_map(lambda a: a[0], base["final_norm"]),
              cfg.norm_eps)
     if all_logits:
-        return _serve_logits(h, base["embed"]["tok"]), new_caches
+        return _serve_logits(h, head_weights(base, cfg)), new_caches
     h_sel = h[jnp.arange(h.shape[0]), sel]                  # (B, d)
-    logits = _serve_logits(h_sel, base["embed"]["tok"])
+    logits = _serve_logits(h_sel, head_weights(base, cfg))
     return logits, new_caches
 
 
@@ -545,6 +639,6 @@ def decode_step(base, cfg: ModelConfig, spec, broadcast, per_layer, token,
     h = norm(h, jax.tree_util.tree_map(lambda a: a[0], base["final_norm"]),
              cfg.norm_eps)
     if all_logits:
-        return _serve_logits(h, base["embed"]["tok"]), new_caches
-    logits = _serve_logits(h[:, 0], base["embed"]["tok"])
+        return _serve_logits(h, head_weights(base, cfg)), new_caches
+    logits = _serve_logits(h[:, 0], head_weights(base, cfg))
     return logits, new_caches

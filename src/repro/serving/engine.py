@@ -276,6 +276,12 @@ class Engine:
                  seed: int = 0,
                  kernels: KernelConfig = KernelConfig(),
                  serve: Optional[ServeConfig] = None):
+        if model_cfg.is_mla or model_cfg.experts_held:
+            raise NotImplementedError(
+                f"{model_cfg.name}: serving has no latent paged cache for "
+                "multi-head latent attention and no expert task routing "
+                "for a held share of the experts; this configuration "
+                "trains only")
         for mixer, _ in model_cfg.block_pattern:
             if mixer != "attn":
                 raise NotImplementedError(

@@ -26,10 +26,16 @@ its bare name and the arguments as its stats.
 
 Inside the jitted graphs, ``jax.named_scope`` names the phases (``forward``,
 ``gradient``, ``optimizer``, ``compression``; in the decode loop ``model``,
-``sample``, ``bookkeeping``); they change HLO metadata only. To see device
-time by scope in a trace:
+``sample``, ``bookkeeping``); they change HLO metadata only. Inside the
+layers, ``MODEL_SCOPES`` name the latent attention (``mla``) and the parts
+of the dropless expert layer (``moe.router``, ``moe.experts``,
+``moe.shared``); that layer's step metrics carry the counters
+``moe_pairs_here`` (pairs routed to held experts, summed over layers) and
+``moe_max_expert_rows`` (the most rows one held expert took in a layer).
+To see device time by scope in a trace, or under each of some named
+scopes wherever they sit:
 
-    python -m repro.tracing <file.xplane.pb>
+    python -m repro.tracing <file.xplane.pb> [--scopes mla,moe.experts]
 """
 from __future__ import annotations
 
@@ -46,6 +52,10 @@ SPANS = (
     "trainer.step", "trainer.batch", "trainer.dispatch", "trainer.wait",
     "trainer.dmrg", "trainer.checkpoint",
 )
+
+#: named scopes inside the model's layers (models/attention.py,
+#: models/moe.py)
+MODEL_SCOPES = ("mla", "moe.router", "moe.experts", "moe.shared")
 
 
 def span(name: str, **args):
@@ -180,13 +190,36 @@ def scope_times(path: str, depth: int = 2) -> dict:
     return {k: v / max(planes, 1) for k, v in acc.most_common()}
 
 
+def named_scope_times(path: str, names) -> dict:
+    """Device seconds of the trace at ``path`` under each named scope of
+    ``names``, wherever it sits in an op's name (forward, backward or
+    remat's recompute), leaf operations only, averaged over the device
+    planes; "(other)" holds the rest."""
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    acc, planes = collections.Counter({n: 0.0 for n in names}), 0
+    for plane in _grouped(space)[1]:
+        fields = _grouped(plane)
+        if not re.match(r"^/device:TPU:\d+$", _text(fields[2])):
+            continue
+        planes += 1
+        for op, s, e in _leaves(_device_ops(fields)):
+            parts = op.split("/")
+            hit = next((n for n in names if n in parts), "(other)")
+            acc[hit] += (e - s) * 1e-12
+    return {k: v / max(planes, 1) for k, v in acc.most_common()}
+
+
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
-    if len(argv) != 1:
-        print("usage: python -m repro.tracing <file.xplane.pb>",
-              file=sys.stderr)
+    if len(argv) == 3 and argv[1] == "--scopes":
+        times = named_scope_times(argv[0], argv[2].split(","))
+    elif len(argv) == 1:
+        times = scope_times(argv[0])
+    else:
+        print("usage: python -m repro.tracing <file.xplane.pb> "
+              "[--scopes a,b,...]", file=sys.stderr)
         return 2
-    times = scope_times(argv[0])
     total = sum(times.values()) or 1.0
     for k, v in times.items():
         print(f"{v:12.6f} s {100 * v / total:6.2f}%  {k}")

@@ -22,6 +22,7 @@ from repro.config.base import ModelConfig, OptimizerConfig, TrainConfig
 from repro.distributed.compression import GradCompressor
 from repro.kernels import dispatch as kernel_dispatch
 from repro.models import model as model_lib
+from repro.models import moe as moe_lib
 from repro.optim import adamw
 from repro.peft import api as peft_api
 
@@ -67,6 +68,13 @@ def reinit_after_dmrg(state: TrainState, new_adapter,
                       step=state.step)
 
 
+def _join_microbatches(v):
+    """A record stacked over microbatches (nmb, layers, tokens, ...) as
+    one over the whole batch's tokens (layers, nmb·tokens, ...)."""
+    v = jnp.moveaxis(v, 0, 1)
+    return v.reshape(v.shape[:1] + (-1,) + v.shape[3:])
+
+
 def make_train_step(cfg: ModelConfig, spec: peft_api.AdapterSpec,
                     opt_cfg: OptimizerConfig, train_cfg: TrainConfig,
                     total_steps: int, *, chunk: int = 0,
@@ -107,7 +115,9 @@ def make_train_step(cfg: ModelConfig, spec: peft_api.AdapterSpec,
                 (grads, lsum), ms = jax.lax.scan(micro, zero, mbs)
                 grads = jax.tree_util.tree_map(lambda g: g / nmb, grads)
                 loss_val = lsum / nmb
-                metrics = {k: jnp.mean(v) for k, v in ms.items()}
+                metrics = {k: (_join_microbatches(v)
+                               if k in moe_lib.RECORDS else jnp.mean(v))
+                           for k, v in ms.items()}
             else:
                 (loss_val, metrics), grads = grad_fn(state.adapter, base,
                                                      frozen, batch)

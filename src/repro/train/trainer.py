@@ -168,7 +168,8 @@ class Trainer:
             self.state, metrics = self.step_fn(self.state, self.base,
                                                self.frozen, batch)
         with tracing.span("trainer.wait"):
-            metrics = {k: float(v) for k, v in metrics.items()}
+            metrics = {k: float(v) if np.ndim(v) == 0 else np.asarray(v)
+                       for k, v in metrics.items()}
         dt = time.perf_counter() - t0
         self.watchdog.step(step, dt)
         metrics["step_time_s"] = dt

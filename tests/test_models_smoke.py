@@ -63,7 +63,7 @@ def test_full_config_matches_assignment(arch):
     cfg = registry.get_config(arch)
     expected = {
         "granite-moe-1b-a400m": (24, 1024, 16, 8, 512, 49155, 32, 8),
-        "kimi-k2-1t-a32b": (61, 7168, 64, 8, 2048, 163840, 384, 8),
+        "kimi-k2-1t-a32b": (61, 7168, 64, 64, 2048, 163840, 384, 8),
         "paligemma-3b": (18, 2048, 8, 1, 16384, 257216, 0, 0),
         "mistral-large-123b": (88, 12288, 96, 8, 28672, 32768, 0, 0),
         "stablelm-1.6b": (24, 2048, 32, 32, 5632, 100352, 0, 0),

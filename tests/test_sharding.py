@@ -64,9 +64,12 @@ def test_sharded_train_step_runs_and_matches_single_device():
     """)
 
 
-def test_moe_ep_matches_local_path():
-    """shard_map expert parallelism == the no-mesh local path, exactly."""
-    _run("""
+@pytest.mark.parametrize("scoring,dispatch", [("softmax", "capacity"),
+                                               ("sigmoid", "dropless")])
+def test_moe_ep_matches_local_path(scoring, dispatch):
+    """shard_map expert parallelism == the no-mesh local path, exactly, for
+    either router and dispatch; the counters agree too."""
+    _run(f"""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.launch.mesh import make_host_mesh
@@ -78,19 +81,23 @@ def test_moe_ep_matches_local_path():
                           num_heads=2, num_kv_heads=2, d_ff=8, vocab_size=32,
                           block_pattern=(('attn','moe'),), num_experts=4,
                           experts_per_token=2, moe_capacity_factor=8.0,
+                          moe_scoring='{scoring}', moe_dispatch='{dispatch}',
                           param_dtype=jnp.float32, compute_dtype=jnp.float32)
         x = jax.random.normal(key, (4, 8, 16))
-        w = {'router': jax.random.normal(key, (16, 4)),
+        w = {{'router': jax.random.normal(key, (16, 4)),
+             'router_bias': jnp.zeros((4,)),
              'e_wg': jax.random.normal(key, (4, 16, 8)),
              'e_wu': jax.random.normal(jax.random.PRNGKey(1), (4, 16, 8)),
-             'e_wd': jax.random.normal(jax.random.PRNGKey(2), (4, 8, 16))}
-        y_local, _ = MO.moe_ffn(x, w, NO_ADAPTER, cfg)
+             'e_wd': jax.random.normal(jax.random.PRNGKey(2), (4, 8, 16))}}
+        y_local, a_local = MO.moe_ffn(x, w, NO_ADAPTER, cfg)
         mesh = make_host_mesh(2, 4)   # model axis 4 -> 1 expert per shard
         with jax.set_mesh(mesh):
-            y_ep, _ = jax.jit(lambda x, w: MO.moe_ffn(x, w, NO_ADAPTER,
-                                                      cfg))(x, w)
+            y_ep, a_ep = jax.jit(lambda x, w: MO.moe_ffn(x, w, NO_ADAPTER,
+                                                         cfg))(x, w)
         err = float(jnp.abs(y_local - y_ep).max())
         assert err < 1e-4, err
+        for k in MO.COUNTERS:
+            assert float(a_local[k]) == float(a_ep[k]), (k, a_local, a_ep)
         print('OK', err)
     """)
 

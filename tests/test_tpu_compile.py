@@ -1,5 +1,6 @@
 """Compile guard for the TPU: every main-path Pallas kernel, at
-stablelm-1.6b widths, compiled natively (``interpret=False``) for a
+stablelm-1.6b widths (Kimi K2's for latent attention and the grouped
+expert matmul), compiled natively (``interpret=False``) for a
 described — not attached — TPU v5e (``v5e:2x2``).
 
 Interpret mode cannot see what the chip's compiler refuses: block shapes
@@ -67,6 +68,11 @@ _POOL, _SPOOL = (NBLK, KV, PAGE, HD), (NBLK, KV, 1, PAGE)
 #: is spec_k + 1 with spec_k 3
 SERVE_SLOTS, SERVE_NBLK, SERVE_PTAB, VERIFY_CHUNK = 32, 640, 65, 4
 _SERVE_POOL = (SERVE_NBLK, KV, PAGE, HD)
+#: Kimi K2's latent attention and expert widths (configs/kimi_k2.py)
+MLA_H, MLA_QK, MLA_V, MLA_SCALE = 64, 192, 128, 192 ** -0.5 * 1.8133
+KIMI_D, KIMI_FF = 7168, 2048
+#: rows of one 4096-token chunk of the dropless layer (8 held experts)
+GMM_R = -(-(4096 * 8 + 8 * 127) // 128) * 128
 _SERVE_SPOOL = (SERVE_NBLK, KV, 1, PAGE)
 
 
@@ -129,6 +135,28 @@ CASES = {
         [((SLOTS, CHUNK, H, HD), bf), (_POOL, i8), (_POOL, i8),
          ((SLOTS, PTAB), i32), ((SLOTS,), i32), (_SPOOL, f32),
          (_SPOOL, f32)]),
+    # Kimi K2's latent attention: q/k head dim 192, v head dim 128, YaRN's
+    # softmax scale
+    "flash_attention_mla_fwd": (
+        lambda q, k, v: ops.flash_attention_fwd(q, k, v, scale=MLA_SCALE,
+                                                **NAT),
+        [((1, T, MLA_H, MLA_QK), bf)] * 2 + [((1, T, MLA_H, MLA_V), bf)]),
+    "flash_attention_mla_bwd": (
+        lambda q, k, v, o, lse, g: ops.flash_attention_bwd(
+            q, k, v, o, lse, g, scale=MLA_SCALE, **NAT),
+        [((1, T, MLA_H, MLA_QK), bf)] * 2 + [((1, T, MLA_H, MLA_V), bf)] * 2
+        + [((1, MLA_H, T), f32), ((1, T, MLA_H, MLA_V), bf)]),
+    # the held experts' grouped matmuls at Kimi K2 widths: one chunk's rows
+    # buffer, up and down projections, and the backward's transposed call
+    "moe_gmm_up": (
+        lambda x, w, g, u: ops.grouped_matmul(x, w, g, u, tm=128, **NAT),
+        [((GMM_R, KIMI_D), bf), ((8, KIMI_D, KIMI_FF), bf),
+         ((GMM_R // 128,), i32), ((1,), i32)]),
+    "moe_gmm_down_transposed": (
+        lambda x, w, g, u: ops.grouped_matmul(x, w, g, u, tm=128,
+                                              transpose_w=True, **NAT),
+        [((GMM_R, KIMI_D), bf), ((8, KIMI_FF, KIMI_D), bf),
+         ((GMM_R // 128,), i32), ((1,), i32)]),
     "paged_decode_attention_serve": _paged_serve(CHUNK, bf),
     "paged_decode_attention_serve_int8": _paged_serve(CHUNK, i8),
     "paged_decode_attention_verify": _paged_serve(VERIFY_CHUNK, bf),
@@ -148,6 +176,11 @@ KERNEL_NAMES = {
     "flash_attention_fwd": {"flash_attention_fwd"},
     "flash_attention_bwd": {"flash_attention_bwd_dq",
                             "flash_attention_bwd_dkv"},
+    "flash_attention_mla_fwd": {"flash_attention_fwd"},
+    "flash_attention_mla_bwd": {"flash_attention_bwd_dq",
+                                "flash_attention_bwd_dkv"},
+    "moe_gmm_up": {"moe_gmm"},
+    "moe_gmm_down_transposed": {"moe_gmm"},
     "decode_attention": {"decode_attention"},
     "paged_decode_attention": {"paged_decode_attention"},
     "paged_decode_attention_int8": {"paged_decode_attention"},

@@ -164,3 +164,36 @@ def test_scope_survey_of_a_recorded_tpu_trace():
     assert sum(durs) > 0
     # ProfileData rounds each duration to whole nanoseconds
     assert abs(sum(times.values()) - sum(durs)) <= 1e-9 * len(durs)
+
+
+def test_latent_moe_layers_carry_their_named_scopes():
+    """Latent attention and the dropless expert layer's router, held
+    experts and shared expert each name their ops, in the forward, the
+    backward and remat's recompute, where ``named_scope_times`` finds
+    them."""
+    import dataclasses
+    kimi = dataclasses.replace(
+        registry.get_smoke_config("kimi-k2-1t-a32b"), experts_held=4,
+        expert_offset=2).validate()
+    run = RunConfig(model=kimi, shape=SHAPES["train_4k"],
+                    adapter_kind="metatt", adapter_rank=4,
+                    train=TrainConfig(remat="block"))
+    data = LMStream(vocab_size=kimi.vocab_size, seq_len=16, batch=2,
+                    seed=1, branching=2)
+    tr = Trainer(run=run, data=data, total_steps=1)
+    text = tr.step_fn.lower(tr.state, tr.base, tr.frozen,
+                            tr._next_batch(0)).compile().as_text()
+    parts = {p for n in re.findall(r'op_name="([^"]*)"', text)
+             for p in n.split("/")}
+    assert set(tracing.MODEL_SCOPES) <= parts
+
+
+def test_named_scope_survey_of_a_recorded_tpu_trace():
+    """Every leaf device operation lands under one named scope or
+    "(other)": the survey's total is the scope survey's."""
+    path = os.path.join(ROOT, "bench", "tests", "data", "small.xplane.pb")
+    named = tracing.named_scope_times(path, tracing.MODEL_SCOPES)
+    assert list(named)[0] == "(other)" and set(named) == {
+        "(other)", *tracing.MODEL_SCOPES}
+    assert abs(sum(named.values())
+               - sum(tracing.scope_times(path).values())) < 1e-12
